@@ -159,7 +159,7 @@ def _rolling_case(b, hq, hkv, d, cap, window, cache_len, tq, iters,
         return kops.cascade_attention(
             q, ck, cv, blk_k, blk_v, cache_len=clen, q_abs=q_abs,
             tree_mask=tree, window=window, rolling=True,
-            n_splits=n_splits, bk=bk, interpret=True, layout="BTHD")
+            n_splits=n_splits, bk=bk, layout="BTHD")
 
     args = (q, ck, cv, blk_k, blk_v, clen, q_abs)
     yg = jax.jit(gather_leg)(*args)

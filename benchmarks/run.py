@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
@@ -29,6 +30,8 @@ def main() -> None:
                     help="only run benches that need no trained artifacts")
     args = ap.parse_args()
     args.only = args.only or args.suite
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import engine_bench, kernel_bench, serving_bench
     sections = [("kernels", lambda q: kernel_bench.run(q)),
@@ -60,6 +63,7 @@ def main() -> None:
                 ("fig2a", lambda q: fig2a_calibration.run(q)),
             ]
 
+    failed = []
     for name, fn in sections:
         if args.only and args.only != name:
             continue
@@ -67,9 +71,13 @@ def main() -> None:
         print(f"\n===== {name} =====")
         try:
             fn(args.quick)
-        except Exception as e:  # noqa
-            print(f"SECTION FAILED: {name}: {e!r}")
+        except Exception:  # a boundary: report, run the rest, fail at exit
+            traceback.print_exc()
+            print(f"SECTION FAILED: {name}")
+            failed.append(name)
         print(f"===== {name} done ({time.time() - t0:.0f}s) =====")
+    if failed:
+        sys.exit(f"failed sections: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

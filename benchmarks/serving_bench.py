@@ -722,29 +722,16 @@ def run_sharded(quick: bool = False) -> None:
     per-shard pool placement (``pool_shard_slots`` slots/shard, identical
     per-shard utilization — page identity is global) plus the
     ``decode_collective_bytes`` the verify LSE-psum moves per run.
-    Re-execs itself under ``XLA_FLAGS=--xla_force_host_platform_device_
-    count=4`` when fewer than 4 devices are visible (the usual CPU case).
+    Runs in this process, which must see at least 4 devices: on the CPU,
+    start it with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
     """
     import jax
-    if jax.device_count() >= 4:
-        _run_sharded_inline(quick)
-        return
-    import os
-    import subprocess
-    root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = str(root / "src")
-    env.pop("JAX_PLATFORMS", None)
-    cmd = [sys.executable, "-m", "benchmarks.serving_bench", "--sharded"]
-    if quick:
-        cmd.append("--quick")
-    out = subprocess.run(cmd, env=env, cwd=str(root), capture_output=True,
-                         text=True, timeout=1800)
-    sys.stdout.write(out.stdout)
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr[-3000:] + "\n")
-        raise RuntimeError("sharded serving bench subprocess failed")
+    if jax.device_count() < 4:
+        raise RuntimeError(
+            f"sharded serving bench needs 4 devices, {jax.device_count()} "
+            f"visible; on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=4")
+    _run_sharded_inline(quick)
 
 
 if __name__ == "__main__":

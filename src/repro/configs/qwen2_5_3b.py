@@ -8,7 +8,7 @@ def full() -> ModelConfig:
         name="qwen2.5-3b", family=Family.DENSE,
         num_layers=36, d_model=2048, num_heads=16, num_kv_heads=2,
         d_ff=11008, vocab_size=151936, qkv_bias=True,
-        rope_theta=1e6, max_seq_len=32768,
+        rope_theta=1e6, max_seq_len=32768, tie_embeddings=True,
     )
 
 

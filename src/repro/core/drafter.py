@@ -20,6 +20,7 @@ The same module runs the EAGLE-style autoregressive baseline by switching
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -45,6 +46,7 @@ class DrafterConfig:
     gamma: int = 16
     rope_theta: float = 10000.0
     dtype: str = "bfloat16"
+    param_dtype: str = "float32"
     norm_eps: float = 1e-6
     causal: bool = False               # True => EAGLE-style AR drafter
     # Feature-cache read path, mirroring ModelConfig.attn_impl (jit-static
@@ -70,7 +72,10 @@ class DrafterConfig:
         return self.vocab_size         # embedding table has vocab+1 rows
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def drafter_init(key, dcfg: DrafterConfig):
+    """Parameters in ``dcfg.param_dtype``, drawn in float32 and cast down
+    inside this jit (see ``lm.lm_init``)."""
     ks = pm.split(key, 4 + dcfg.num_layers)
     hq, hkv, dh = dcfg.num_heads, dcfg.num_kv_heads, dcfg.head_dim
     d = dcfg.d_model
@@ -92,7 +97,7 @@ def drafter_init(key, dcfg: DrafterConfig):
             "ln2": rmsnorm_init(d),
             "mlp": mlp_init(kk[4], d, dcfg.d_ff, gated=True),
         }
-    return p
+    return pm.cast_floating(p, dcfg.param_dtype)
 
 
 # ----------------------------------------------------------- feature cache --

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as sh
-from repro.distributed import compat
 from repro.models.layers import act_fn
 
 
@@ -100,7 +99,7 @@ def moe_apply_ep(p, x, cfg):
         wo = wo[0]
         wg = wg[0] if gated else None
         y = jnp.zeros_like(x_loc)
-        y = compat.pvary(y, (ax,))
+        y = jax.lax.pcast(y, (ax,), to="varying")
         flat_idx = idx_loc.reshape(-1)                       # [T_loc*k]
         flat_gate = gates_loc.reshape(-1)
         src = jnp.repeat(jnp.arange(t_loc), k)
@@ -110,7 +109,8 @@ def moe_apply_ep(p, x, cfg):
             pos = jnp.cumsum(sel.astype(jnp.int32)) - 1
             ok = sel & (pos < cap)
             wpos = jnp.where(ok, pos, cap)
-            xin0 = compat.pvary(jnp.zeros((cap + 1, d), cast), (ax,))
+            xin0 = jax.lax.pcast(jnp.zeros((cap + 1, d), cast), (ax,),
+                                 to="varying")
             xin = xin0.at[wpos].add(
                 jnp.where(ok[:, None], x_loc[src], 0))[:cap]
             h = xin @ wi[j].astype(cast)
@@ -124,7 +124,7 @@ def moe_apply_ep(p, x, cfg):
             y = y.at[src].add(picked * flat_gate[:, None])
         return jax.lax.psum(y, ax)
 
-    y2 = compat.shard_map(
+    y2 = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec), wspecs, wspecs
                   if gated else P(), wspecs),

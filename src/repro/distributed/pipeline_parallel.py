@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as sh
-from repro.distributed import compat
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
@@ -44,8 +43,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
         rank = jax.lax.axis_index(axis)
         n_ticks = m + s - 1
         buf = jnp.zeros_like(xs[0])
-        buf = compat.pvary(buf, (axis,))
-        outs = compat.pvary(jnp.zeros_like(xs), (axis,))
+        buf = jax.lax.pcast(buf, (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
 
         def tick(t, carry):
             buf, outs = carry
@@ -76,7 +75,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
     other = tuple(a for a in mesh.axis_names if a != axis)
     pspec = P(axis)
     xspec = P()
-    return compat.shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: pspec, stage_params), xspec),
         out_specs=xspec, check_vma=True,

@@ -178,6 +178,30 @@ def shard_put(x, logical: Sequence[Optional[str]]):
     return jax.device_put(x, NamedSharding(mesh, spec))
 
 
+def replicate_onto(tree, mesh: Mesh):
+    """Replicate over ``mesh`` every leaf of ``tree`` that lives on a single
+    device; leaves already spread over several devices keep their sharding.
+
+    A leaf's own buffer becomes the copy on its device when that device is
+    in the mesh, so the device holds no second copy of the weights and the
+    caller's arrays stay valid (the single-device and sharded engines of
+    one process share them).
+    """
+    rep = NamedSharding(mesh, P())
+    devs = list(mesh.devices.flat)
+
+    def one(x):
+        if not isinstance(x, jax.Array):      # host data: one plain copy
+            return jax.device_put(x, rep)
+        if len(x.devices()) > 1:
+            return x
+        (home,) = x.devices()
+        shards = [x if d == home else jax.device_put(x, d) for d in devs]
+        return jax.make_array_from_single_device_arrays(x.shape, rep, shards)
+
+    return jax.tree.map(one, tree)
+
+
 # ---------------------------------------------------------------------------
 # Parameter sharding by path name.
 #

@@ -23,7 +23,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.attention import (NEG_INF, attend, attend_chunked,
                                     merge_attn_stats, softcap)
 from repro.distributed import sharding as sh
-from repro.distributed import compat
 
 
 def kv_seq_axis() -> Optional[str]:
@@ -105,8 +104,8 @@ def sharded_cache_attend(q, cache_k, cache_v, blk_k, blk_v, *, cache_len,
         # ---- cache slice partials ----
         # mask by absolute key position (rolling caches store position
         # p at slot p % cap, recovered against the local slot offset)
-        acc, m, l = _cache_stats(compat.pvary(qs, (axis,)), ck, cv,
-                                 offset=offset, cap=cap,
+        qv = jax.lax.pcast(qs, (axis,), to="varying")
+        acc, m, l = _cache_stats(qv, ck, cv, offset=offset, cap=cap,
                                  clen=cl, qab=qab, window=window,
                                  attn_softcap=attn_softcap, rolling=rolling,
                                  kv_chunk=kv_chunk, vary_axes=vary_cache)
@@ -123,7 +122,7 @@ def sharded_cache_attend(q, cache_k, cache_v, blk_k, blk_v, *, cache_len,
     # check_vma=True: psum/pmax establish replication over the kv_seq axis,
     # so shard_map emits NO output all-gather (the check_vma=False baseline
     # re-gathered the merged output redundantly — §Perf iteration 1).
-    return compat.shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(bspec), P(bspec, axis), P(bspec, axis), P(bspec),
                   P(bspec), P(bspec), P(bspec), P(bspec)),
@@ -196,7 +195,7 @@ def sharded_paged_cache_attend(q, pool_k, pool_v, table, blk_k, blk_v, *,
     if read_impl == "pallas":
         from repro.kernels import cascade_attention as casc
         from repro.kernels import ops as kops
-        interpret = (kops._default_interpret() if interpret is None
+        interpret = (kops.default_interpret() if interpret is None
                      else interpret)
 
     mesh = sh.active_mesh()
@@ -240,11 +239,11 @@ def sharded_paged_cache_attend(q, pool_k, pool_v, table, blk_k, blk_v, *,
             # kernel on the local pool slice: one grid step per local page
             # run; stride/offset place the run at its absolute positions
             acc, m, l = casc.cascade_phase1_paged(
-                jnp.swapaxes(compat.pvary(qs, (axis,)), 1, 2),
+                jnp.swapaxes(jax.lax.pcast(qs, (axis,), to="varying"), 1, 2),
                 jnp.swapaxes(pk, 1, 2), jnp.swapaxes(pv, 1, 2),
-                compat.pvary(tbl, (axis,)),
-                cache_len=compat.pvary(cl, (axis,)),
-                q_abs=compat.pvary(qab, (axis,)),
+                jax.lax.pcast(tbl, (axis,), to="varying"),
+                cache_len=jax.lax.pcast(cl, (axis,), to="varying"),
+                q_abs=jax.lax.pcast(qab, (axis,), to="varying"),
                 window=None, attn_softcap=attn_softcap,
                 pos_stride=page_size, pos_offset=ax_idx * page_loc,
                 interpret=interpret)
@@ -267,7 +266,7 @@ def sharded_paged_cache_attend(q, pool_k, pool_v, table, blk_k, blk_v, *,
             pos = ((t // page_loc) * page_size + ax_idx * page_loc
                    + (t % page_loc))[None, None, :]
             acc, m, l = _cache_stats(
-                compat.pvary(qs, (axis,)), vk, vv, offset=0,
+                jax.lax.pcast(qs, (axis,), to="varying"), vk, vv, offset=0,
                 cap=mp * page_size, clen=cl, qab=qab, window=None,
                 attn_softcap=attn_softcap, rolling=False, kv_chunk=kv_chunk,
                 vary_axes=vary_cache, pos=pos)
@@ -279,7 +278,7 @@ def sharded_paged_cache_attend(q, pool_k, pool_v, table, blk_k, blk_v, *,
         return merge_attn_stats([(acc_g, m_g, l_g), (acc_b, m_b, l_b)],
                                 qs.shape, qs.dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(bspec), P(None, axis), P(None, axis), P(bspec),
                   P(bspec), P(bspec), P(bspec), P(bspec), P(bspec)),

@@ -42,8 +42,17 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _phase1_kernel(cache_len_ref, q_abs_ref,                  # SMEM
-                   q_ref, k_ref, v_ref,                       # VMEM blocks
+def _q_abs_col(q_abs, b, tq):
+    """Per-query absolute positions as an int32 ``[B, Tq, 1]`` VMEM column.
+
+    Mosaic loads only scalars from SMEM, so the ``[Tq]`` vector the mask
+    compares against each key row rides in as a VMEM block instead."""
+    return jnp.broadcast_to(
+        jnp.asarray(q_abs, jnp.int32).reshape(-1, tq), (b, tq))[..., None]
+
+
+def _phase1_kernel(cache_len_ref,                             # SMEM
+                   q_abs_ref, q_ref, k_ref, v_ref,            # VMEM blocks
                    acc_ref, m_ref, l_ref,                     # outputs
                    racc, rm, rl,                              # scratch
                    *, bk, nk_inner, tq, window, softcap, scale, rolling,
@@ -75,10 +84,9 @@ def _phase1_kernel(cache_len_ref, q_abs_ref,                  # SMEM
 
     clen = cache_len_ref[b]
     base = (s * nk_inner + jj) * bk
-    slot = base + jax.lax.broadcasted_iota(jnp.int32, (bk,), 0)
+    slot = base + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)  # [1, bk]
     live = slot < cap          # padded slots carry no cache data
-    qpos = q_abs_ref[pl.dslice(b * tq, tq)]                  # [tq]
-    qp = qpos[:, None]
+    qp = q_abs_ref[0]                                        # [tq, 1]
     if rolling:
         # slot j holds the largest t < clen with t % cap == j; rem (not
         # mod) is safe: last - slot < 0 only pre-wrap (clen <= cap, so
@@ -94,12 +102,12 @@ def _phase1_kernel(cache_len_ref, q_abs_ref,                  # SMEM
         ok &= kpos > (qp - window)
     sc = jnp.where(ok, sc, NEG_INF)
 
-    m_prev = rm[...]
-    m_new = jnp.maximum(m_prev, sc.max(axis=1))
-    p = jnp.exp(sc - m_new[:, None])
+    m_prev = rm[...]                                         # [tq, 1]
+    m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+    p = jnp.exp(sc - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    rl[...] = rl[...] * alpha + p.sum(axis=1)
-    racc[...] = racc[...] * alpha[:, None] + jax.lax.dot_general(
+    rl[...] = rl[...] * alpha + p.sum(axis=1, keepdims=True)
+    racc[...] = racc[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
     rm[...] = m_new
 
@@ -140,24 +148,18 @@ def cascade_phase1(q, cache_k, cache_v, *, cache_len, q_abs, window=None,
 
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
-    qa = jnp.broadcast_to(
-        jnp.asarray(q_abs, jnp.int32).reshape(b, tq), (b, tq)).reshape(-1)
+    qa = _q_abs_col(q_abs, b, tq)
 
     kernel = functools.partial(
         _phase1_kernel, bk=bk, nk_inner=nk_inner, tq=tq, window=window,
         softcap=attn_softcap, scale=scale, rolling=rolling, cap=s_len)
 
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hq, n_splits, tq, d), jnp.float32),
-        jax.ShapeDtypeStruct((b, hq, n_splits, tq), jnp.float32),
-        jax.ShapeDtypeStruct((b, hq, n_splits, tq), jnp.float32),
-    ]
     acc, m, l = pl.pallas_call(
         kernel,
         grid=(b, hq, n_splits, nk_inner),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, tq, 1), lambda b_, h, s, j: (b_, 0, 0)),
             pl.BlockSpec((1, 1, tq, d), lambda b_, h, s, j: (b_, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h, s, j, g=g, nki=nk_inner:
@@ -166,21 +168,29 @@ def cascade_phase1(q, cache_k, cache_v, *, cache_len, q_abs, window=None,
                          lambda b_, h, s, j, g=g, nki=nk_inner:
                          (b_, h // g, s * nki + j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, tq, d),
-                         lambda b_, h, s, j: (b_, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, tq), lambda b_, h, s, j: (b_, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, tq), lambda b_, h, s, j: (b_, h, s, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tq, d), jnp.float32),
-            pltpu.VMEM((tq,), jnp.float32),
-            pltpu.VMEM((tq,), jnp.float32),
-        ],
-        out_shape=out_shape,
+        out_specs=[pl.BlockSpec((1, 1, 1, tq, n),
+                                lambda b_, h, s, j: (b_, h, s, 0, 0))
+                   for n in (d, 1, 1)],
+        scratch_shapes=_partial_scratch(tq, d),
+        out_shape=_partial_shapes(b, hq, n_splits, tq, d),
         interpret=interpret,
     )(clen, qa, q, cache_k, cache_v)
-    return acc, m, l
+    return acc, m[..., 0], l[..., 0]
+
+
+def _partial_shapes(b, hq, n_splits, tq, d):
+    """Phase-1 outputs acc ``[B,Hq,ns,Tq,D]`` and m/l ``[B,Hq,ns,Tq,1]``.
+
+    m/l keep a trailing unit lane dim: a ``(.., 1, Tq)`` block would put a
+    unit dim second-minor, which Mosaic's (8, 128) tiling refuses."""
+    return [jax.ShapeDtypeStruct((b, hq, n_splits, tq, n), jnp.float32)
+            for n in (d, 1, 1)]
+
+
+def _partial_scratch(tq, d):
+    """Running flash state: acc ``[Tq, D]``, m and l ``[Tq, 1]`` (2-D, as
+    Mosaic requires of VMEM scratch)."""
+    return [pltpu.VMEM((tq, n), jnp.float32) for n in (d, 1, 1)]
 
 
 def _merge_with_tree_block(q, blk_k, blk_v, acc, m, l, *, tree_mask,
@@ -240,8 +250,8 @@ def cascade_attention(q, cache_k, cache_v, blk_k, blk_v, *, cache_len,
 
 
 # ------------------------------------------------------------- paged -------
-def _phase1_paged_kernel(pt_ref, cache_len_ref, q_abs_ref, off_ref,  # scalar prefetch
-                         q_ref, k_ref, v_ref,                 # VMEM blocks
+def _phase1_paged_kernel(pt_ref, cache_len_ref, off_ref,   # scalar prefetch
+                         q_abs_ref, q_ref, k_ref, v_ref,      # VMEM blocks
                          acc_ref, m_ref, l_ref,               # outputs
                          racc, rm, rl,                        # scratch
                          *, page, pos_stride, nk_inner, tq, window, softcap,
@@ -281,20 +291,19 @@ def _phase1_paged_kernel(pt_ref, cache_len_ref, q_abs_ref, off_ref,  # scalar pr
 
     clen = cache_len_ref[b]
     base = (s * nk_inner + jj) * pos_stride + off_ref[0]
-    kpos = base + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
-    qpos = q_abs_ref[pl.dslice(b * tq, tq)]                  # [tq]
-    qp = qpos[:, None]
+    kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)  # [1, page]
+    qp = q_abs_ref[0]                                        # [tq, 1]
     ok = (kpos < clen) & (kpos <= qp)
     if window is not None:
         ok &= kpos > (qp - window)
     sc = jnp.where(ok, sc, NEG_INF)
 
-    m_prev = rm[...]
-    m_new = jnp.maximum(m_prev, sc.max(axis=1))
-    p = jnp.exp(sc - m_new[:, None])
+    m_prev = rm[...]                                         # [tq, 1]
+    m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+    p = jnp.exp(sc - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    rl[...] = rl[...] * alpha + p.sum(axis=1)
-    racc[...] = racc[...] * alpha[:, None] + jax.lax.dot_general(
+    rl[...] = rl[...] * alpha + p.sum(axis=1, keepdims=True)
+    racc[...] = racc[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
     rm[...] = m_new
 
@@ -357,8 +366,7 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
     pt = jnp.minimum(page_table, n_phys - 1).reshape(-1)      # [B*MP]
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
-    qa = jnp.broadcast_to(
-        jnp.asarray(q_abs, jnp.int32).reshape(b, tq), (b, tq)).reshape(-1)
+    qa = _q_abs_col(q_abs, b, tq)
     off = jnp.asarray(0 if pos_offset is None else pos_offset,
                       jnp.int32).reshape(-1)[:1]
 
@@ -367,7 +375,7 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
         nk_inner=nk_inner, tq=tq, window=window, softcap=attn_softcap,
         scale=scale)
 
-    def kv_map(b_, h, s, j, pt_ref, clen_ref, qa_ref, off_ref, g=g,
+    def kv_map(b_, h, s, j, pt_ref, clen_ref, off_ref, g=g,
                nki=nk_inner, mp=mp, stride=pos_stride):
         # Clamp the logical step to the last LIVE page: Pallas elides the
         # DMA when the resolved block index repeats across grid steps, so
@@ -379,44 +387,29 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
         return (pt_ref[b_ * mp + step], h // g, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=3,
         grid=(b, hq, n_splits, nk_inner),
         in_specs=[
+            pl.BlockSpec((1, tq, 1),
+                         lambda b_, h, s, j, pt_, cl_, off_: (b_, 0, 0)),
             pl.BlockSpec((1, 1, tq, d),
-                         lambda b_, h, s, j, pt_, cl_, qa_, off_:
-                         (b_, h, 0, 0)),
+                         lambda b_, h, s, j, pt_, cl_, off_: (b_, h, 0, 0)),
             pl.BlockSpec((1, 1, page, d), kv_map),
             pl.BlockSpec((1, 1, page, d), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, tq, d),
-                         lambda b_, h, s, j, pt_, cl_, qa_, off_:
-                         (b_, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, tq),
-                         lambda b_, h, s, j, pt_, cl_, qa_, off_:
-                         (b_, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, tq),
-                         lambda b_, h, s, j, pt_, cl_, qa_, off_:
-                         (b_, h, s, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tq, d), jnp.float32),
-            pltpu.VMEM((tq,), jnp.float32),
-            pltpu.VMEM((tq,), jnp.float32),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, 1, tq, n),
+                                lambda b_, h, s, j, pt_, cl_, off_:
+                                (b_, h, s, 0, 0))
+                   for n in (d, 1, 1)],
+        scratch_shapes=_partial_scratch(tq, d),
     )
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hq, n_splits, tq, d), jnp.float32),
-        jax.ShapeDtypeStruct((b, hq, n_splits, tq), jnp.float32),
-        jax.ShapeDtypeStruct((b, hq, n_splits, tq), jnp.float32),
-    ]
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=_partial_shapes(b, hq, n_splits, tq, d),
         interpret=interpret,
-    )(pt, clen, qa, off, q, pool_k, pool_v)
-    return acc, m, l
+    )(pt, clen, off, qa, q, pool_k, pool_v)
+    return acc, m[..., 0], l[..., 0]
 
 
 def cascade_attention_paged(q, pool_k, pool_v, page_table, blk_k, blk_v, *,

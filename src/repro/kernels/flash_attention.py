@@ -1,8 +1,9 @@
 """Pallas TPU flash attention (fwd + bwd) with GQA, causal/window masks,
 gemma-style attention-logit softcap, and KV-length masking.
 
-TARGET: TPU (MXU 128x128; VMEM-tiled via BlockSpec). Validated on CPU with
-``interpret=True`` against the pure-jnp oracle in ``ref.py``.
+TARGET: TPU (MXU 128x128; VMEM-tiled via BlockSpec). Validated on CPU in
+interpret mode against the pure-jnp oracle in ``ref.py``, and compiled for
+a described v5e in tests/test_tpu_compile.py.
 
 Layouts (kernel-internal): q [B, Hq, Tq, D]; k,v [B, Hkv, Tkv, D].
 Grid: (B, Hq, nq, nk) — the kv dimension is the minor (sequential) grid axis,
@@ -24,10 +25,16 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _mask_block(qpos, kpos, *, causal, window, kv_len):
-    m = jnp.ones((qpos.shape[0], kpos.shape[0]), dtype=bool)
-    qp = qpos[:, None]
-    kp = kpos[None, :]
+def _block_pos(i, j, q_off, bq, bk):
+    """Absolute positions of a (q block, kv block) pair as a ``[bq, 1]``
+    column and a ``[1, bk]`` row (Mosaic has no 1-D iota)."""
+    qp = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0) + q_off
+    kp = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    return qp, kp
+
+
+def _mask_block(qp, kp, *, causal, window, kv_len):
+    m = jnp.ones((qp.shape[0], kp.shape[1]), dtype=bool)
     if causal:
         m &= kp <= qp
     if window is not None:
@@ -59,34 +66,36 @@ def _fwd_kernel(q_off_ref, kv_len_ref, q_ref, k_ref, v_ref,  # inputs
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
 
-    q_off = q_off_ref[0]
-    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq,), 0) + q_off
-    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk,), 0)
+    qpos, kpos = _block_pos(i, j, q_off_ref[0], bq, bk)
     kv_len = kv_len_ref[b] if has_kvlen else None
     mask = _mask_block(qpos, kpos, causal=causal, window=window,
                        kv_len=kv_len)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                               # [bq, 1]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
     m_ref[...] = m_new
 
     @pl.when(j == nk - 1)
     def _final():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, q_offset=0, window=None,
                         kv_len=None, attn_softcap=None, scale=None,
                         bq=128, bk=128, interpret=False):
-    """q [B,Hq,Tq,D]; k,v [B,Hkv,Tkv,D] -> (o [B,Hq,Tq,D], lse [B,Hq,Tq])."""
+    """q [B,Hq,Tq,D]; k,v [B,Hkv,Tkv,D] -> (o [B,Hq,Tq,D], lse [B,Hq,Tq]).
+
+    Inside the kernel the per-row statistics (lse, running m/l) carry a
+    trailing unit lane dim, ``[.., bq, 1]``: Mosaic tiles the last two dims
+    (8, 128) and refuses a block whose second-minor dim is a unit slice."""
     b, hq, tq, d = q.shape
     hkv, tkv = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -113,7 +122,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, q_offset=0, window=None,
 
     out_shape = [
         jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        jax.ShapeDtypeStruct((b, hq, tq + pq), jnp.float32),
+        jax.ShapeDtypeStruct((b, hq, tq + pq, 1), jnp.float32),
     ]
     o, lse = pl.pallas_call(
         kernel,
@@ -129,17 +138,17 @@ def flash_attention_fwd(q, k, v, *, causal=True, q_offset=0, window=None,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, i, j: (b_, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         out_shape=out_shape,
         interpret=interpret,
     )(q_off, eff_kv_len, qp, kp, vp)
-    return o[:, :, :tq], lse[:, :, :tq]
+    return o[:, :, :tq], lse[:, :, :tq, 0]
 
 
 # --------------------------------------------------------------- backward --
@@ -168,15 +177,13 @@ def _bwd_dq_kernel(q_off_ref, kv_len_ref, q_ref, k_ref, v_ref, do_ref,
     else:
         s = s_raw
         dcap = None
-    q_off = q_off_ref[0]
-    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq,), 0) + q_off
-    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk,), 0)
+    qpos, kpos = _block_pos(i, j, q_off_ref[0], bq, bk)
     mask = _mask_block(qpos, kpos, causal=causal, window=window,
                        kv_len=kv_len_ref[b])
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)                                # lse, delta [bq, 1]
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     if dcap is not None:
         ds = ds * dcap
     dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())))
@@ -215,16 +222,14 @@ def _bwd_dkv_kernel(q_off_ref, kv_len_ref, q_ref, k_ref, v_ref, do_ref,
     else:
         s = s_raw
         dcap = None
-    q_off = q_off_ref[0]
-    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq,), 0) + q_off
-    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk,), 0)
+    qpos, kpos = _block_pos(i, j, q_off_ref[0], bq, bk)
     mask = _mask_block(qpos, kpos, causal=causal, window=window,
                        kv_len=kv_len_ref[b])
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                       # [bq, bk]
+    p = jnp.exp(s - lse)                                # [bq, bk]
     dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     if dcap is not None:
         ds = ds * dcap
     dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
@@ -252,9 +257,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, q_offset=0,
     qp, kp2, vp = pad4(q, pq), pad4(k, pk), pad4(v, pk)
     dop = pad4(do, pq)
     # lse padding must keep exp(s - lse) == 0 on padded q rows
-    lsep = pad3(lse, pq, 1.0)
+    lsep = pad3(lse, pq, 1.0)[..., None]
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
-    deltap = pad3(delta, pq)
+    deltap = pad3(delta, pq)[..., None]
     nq = (tq + pq) // bq
     nk = (tkv + pk) // bk
     eff_kv_len = jnp.full((b,), tkv, jnp.int32) if kv_len is None else \
@@ -276,8 +281,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, q_offset=0,
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h, i, j, g=g: (b_, h // g, j, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, i, j: (b_, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, i, j: (b_, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d),
                                lambda b_, h, i, j: (b_, h, i, 0)),
@@ -301,8 +306,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, q_offset=0,
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h, j, i, g=g: (b_, h // g, j, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, j, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, j, i: (b_, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, j, i: (b_, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, j, i: (b_, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, j, i: (b_, h, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, i: (b_, h, j, 0)),

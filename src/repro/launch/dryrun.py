@@ -1,17 +1,23 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-"""Multi-pod dry-run (assignment deliverable e).
+"""Multi-pod dry-run on the CPU.
 
 Lowers + compiles every (architecture x input-shape) cell against the
 production meshes (single-pod 16x16 = 256 chips; multi-pod 2x16x16 = 512)
-and records memory analysis, loop-aware FLOP/collective counts, and the
-three roofline terms per cell into experiments/dryrun/<cell>.json.
+of virtual CPU devices and records memory analysis, loop-aware
+FLOP/collective counts, and the three roofline terms per cell into
+experiments/dryrun/<cell>.json.
 
 Run one cell:   python -m repro.launch.dryrun --arch qwen2.5-3b \
                     --shape train_4k --mesh single
 Run everything: python -m repro.launch.dryrun --all --jobs 4
+
+This is an ahead-of-time tool for the CPU: importing it pins JAX to the
+CPU backend with 512 host devices (before JAX initializes), and every
+child it starts inherits that pin, so it never takes a TPU a host has.
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
 import dataclasses
@@ -49,8 +55,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     multi = mesh_kind == "multi"
     n_chips = 512 if multi else 256
     devices = jax.devices()[:n_chips]
-    from repro.distributed.compat import make_mesh as compat_make_mesh
-    mesh = compat_make_mesh(
+    from repro.launch.mesh import mesh_of
+    mesh = mesh_of(
         (2, 16, 16) if multi else (16, 16),
         ("pod", "data", "model") if multi else ("data", "model"),
         devices=devices)
@@ -210,7 +216,7 @@ def main():
             arch, shape, mesh_kind, out = jobs.pop(0)
             cmd = [sys.executable, "-m", "repro.launch.dryrun",
                    "--arch", arch, "--shape", shape, "--mesh", mesh_kind]
-            env = dict(os.environ)
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             env["PYTHONPATH"] = str(Path(__file__).resolve().parents[2])
             proc = subprocess.Popen(cmd, env=env,
                                     stdout=subprocess.DEVNULL,

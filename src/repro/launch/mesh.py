@@ -1,23 +1,30 @@
-"""Production mesh factory (assignment: MULTI-POD DRY-RUN item 1).
+"""Mesh factories.
 
-A FUNCTION, not a module constant — importing this module never touches jax
-device state. Mesh construction goes through
-:mod:`repro.distributed.compat` so the Auto ``axis_types`` kwarg is only
-passed on jax versions that understand it.
+Functions, not module constants: importing this module never touches jax
+device state. Every mesh is built with ``Auto`` axis types, so sharding
+follows the logical-axis rules (``repro.distributed.sharding``) rather than
+explicit-sharding types.
 """
 from __future__ import annotations
 
-from repro.distributed import compat
+import jax
+
+
+def mesh_of(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` if given)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         (jax.sharding.AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return mesh_of(shape, axes)
 
 
 def make_mesh(data: int, model: int, pod: int = 1):
     """Arbitrary mesh for tests / small-scale runs."""
     if pod > 1:
-        return compat.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return compat.make_mesh((data, model), ("data", "model"))
+        return mesh_of((pod, data, model), ("pod", "data", "model"))
+    return mesh_of((data, model), ("data", "model"))

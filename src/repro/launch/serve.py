@@ -1,42 +1,76 @@
 """Serving launcher: ``python -m repro.launch.serve [--mode d2sd] [...]``.
 
-Loads the trained study artifacts (or random weights with --random) and
-serves a batch of synthetic requests through the D2SD engine, printing
-acceptance + throughput statistics.
+Serves a batch of synthetic requests through the D2SD engine and prints
+acceptance and throughput statistics. The weights are the trained study
+artifacts by default; ``--random`` draws a small random target and
+drafters, and ``--arch <id>`` a registered target at its full published
+widths (bf16 parameters) with two production drafters, from a seed,
+reading the KV cache through the Pallas cascade kernels.
 """
 from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 
 import jax
 import numpy as np
 
-from repro.config.base import SpecConfig
+from repro.config.base import ModelConfig, SpecConfig
 from repro.core import pipeline as pl
+from repro.core.drafter import DrafterConfig, drafter_init
 from repro.data.synthetic import SyntheticDataset
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import lm
 from repro.serving.engine import ServingEngine
 
 
-def _mesh_context(args, ap):
-    """``use_sharding`` context for --mesh-data/--mesh-model (nullcontext
-    for the default 1x1). The engine captures the context at CONSTRUCTION
-    and re-enters it around every device-facing call, so only the
+def random_bundle(tcfg: ModelConfig, dcfg: DrafterConfig, spec: SpecConfig,
+                  seed: int = 0) -> pl.SpecBundle:
+    """Target and two drafters (one config) with weights drawn from
+    ``seed``; parameters come out in each config's ``param_dtype``."""
+    tp = lm.lm_init(jax.random.PRNGKey(seed), tcfg)
+    d1 = drafter_init(jax.random.PRNGKey(seed + 1), dcfg)
+    d2 = drafter_init(jax.random.PRNGKey(seed + 2), dcfg)
+    return pl.SpecBundle(tcfg, dcfg, dcfg, spec, tp, d1, d2)
+
+
+def arch_bundle(arch: str, *, mode: str = "d2sd", gamma: int = 16,
+                k: int = 4, temperature: float = 0.0,
+                seed: int = 0) -> pl.SpecBundle:
+    """Registered target ``arch`` at its full widths with bfloat16
+    parameters, plus two ``launch.steps.production_drafter``s, all drawn
+    from ``seed``."""
+    from repro.config.registry import get_config
+    from repro.launch.steps import production_drafter
+    tcfg = dataclasses.replace(get_config(arch), param_dtype="bfloat16")
+    dcfg = production_drafter(tcfg, gamma)
+    spec = SpecConfig(gamma=gamma, top_k_branches=k, mode=mode,
+                      temperature=temperature)
+    return random_bundle(tcfg, dcfg, spec, seed)
+
+
+def mesh_context(mesh_data: int, mesh_model: int,
+                 kv_seq_axis: str = "model"):
+    """``use_sharding`` context over a (data, model) mesh, with the
+    ``kv_seq`` logical axis on ``kv_seq_axis`` ("off" replicates the KV
+    pool); a nullcontext for the default 1x1. The engine captures the
+    context at CONSTRUCTION, places the weights on the mesh and re-enters
+    the context around every device-facing call, so only the
     ``ServingEngine(...)`` call needs to run inside it."""
-    if args.mesh_data * args.mesh_model <= 1:
+    if mesh_data * mesh_model <= 1:
         return contextlib.nullcontext()
-    need = args.mesh_data * args.mesh_model
+    need = mesh_data * mesh_model
     if jax.device_count() < need:
-        ap.error(
-            f"--mesh-data x --mesh-model needs {need} devices but only "
+        raise ValueError(
+            f"a {mesh_data}x{mesh_model} mesh needs {need} devices but only "
             f"{jax.device_count()} are visible; on CPU export XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need}")
     from repro.distributed.sharding import LOGICAL_RULES, use_sharding
     from repro.launch.mesh import make_mesh
     rules = dict(LOGICAL_RULES)
-    rules["kv_seq"] = (None if args.kv_seq_axis == "off"
-                       else args.kv_seq_axis)
-    return use_sharding(make_mesh(args.mesh_data, args.mesh_model), rules)
+    rules["kv_seq"] = None if kv_seq_axis == "off" else kv_seq_axis
+    return use_sharding(make_mesh(mesh_data, mesh_model), rules)
 
 
 def main():
@@ -51,6 +85,11 @@ def main():
     ap.add_argument("--task", default="math")
     ap.add_argument("--random", action="store_true",
                     help="random weights (no study artifacts needed)")
+    ap.add_argument("--arch", default=None,
+                    help="serve this registered target (e.g. qwen2.5-3b) at "
+                         "its full widths with random bf16 weights, two "
+                         "production drafters (gamma defaults to 16) and "
+                         "the Pallas cache-read kernels")
     ap.add_argument("--cache-impl", default="dense",
                     choices=["dense", "paged"],
                     help="KV storage: dense per-row buffers or the page-"
@@ -128,19 +167,23 @@ def main():
                          "keeping the rest of the mesh rules "
                          "(default: model)")
     args = ap.parse_args()
+    try:
+        mesh_ctx = mesh_context(args.mesh_data, args.mesh_model,
+                                args.kv_seq_axis)
+    except ValueError as e:
+        ap.error(str(e))
+    enable_compile_cache()
 
-    if args.random:
+    if args.arch is not None:
+        bundle = pl.with_attn_impl(
+            arch_bundle(args.arch, mode=args.mode, gamma=args.gamma or 16,
+                        k=args.k, temperature=args.temperature), "pallas")
+    elif args.random:
         from repro.configs.paper_target import drafter_small, smoke
-        from repro.core.drafter import drafter_init
-        from repro.models import lm
-        tcfg = smoke()
         dcfg = drafter_small(gamma=args.gamma or 8)
-        tp = lm.lm_init(jax.random.PRNGKey(0), tcfg)
-        d1 = drafter_init(jax.random.PRNGKey(1), dcfg)
-        d2 = drafter_init(jax.random.PRNGKey(2), dcfg)
         spec = SpecConfig(gamma=dcfg.gamma, top_k_branches=args.k,
                           mode=args.mode, temperature=args.temperature)
-        bundle = pl.SpecBundle(tcfg, dcfg, dcfg, spec, tp, d1, d2)
+        bundle = random_bundle(smoke(), dcfg, spec)
     else:
         import sys
         from pathlib import Path
@@ -182,7 +225,7 @@ def main():
             per = max(-(-(a.prompt_len + a.max_new + 2 * g + 8)
                         // args.page_size) for a in trace)
             pool_pages = 2 * args.requests * per
-        with _mesh_context(args, ap):
+        with mesh_ctx:
             eng = ServingEngine(bundle, batch_size=args.requests,
                                 cache_impl=args.cache_impl,
                                 page_size=args.page_size,
@@ -207,7 +250,7 @@ def main():
               f"queue max={sla['queue_depth']['max']}")
         return
 
-    with _mesh_context(args, ap):
+    with mesh_ctx:
         eng = ServingEngine(bundle, batch_size=args.requests,
                             cache_impl=args.cache_impl,
                             page_size=args.page_size,
@@ -228,10 +271,12 @@ def main():
     if stats.get("kv_shards", 1) > 1:
         mesh_note = (f" | kv_shards={stats['kv_shards']} "
                      f"shard_slots={stats['pool_shard_slots']}")
+    dev = jax.devices()[0]
     print(f"mode={args.mode} served {len(eng.done)} requests | "
           f"alpha={stats.get('alpha', 0):.2f} | "
-          f"{stats['tokens_per_s']:.1f} tok/s (CPU) | "
-          f"{stats['cycles']} cycles" + prefix + mesh_note)
+          f"{stats['tokens_per_s']:.1f} tok/s | "
+          f"{stats['cycles']} cycles | device={dev.platform}:"
+          f"{dev.device_kind}x{jax.device_count()}" + prefix + mesh_note)
 
 
 if __name__ == "__main__":

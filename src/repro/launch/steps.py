@@ -36,7 +36,7 @@ def production_drafter(tcfg: ModelConfig, gamma: int = GAMMA_PROD,
         d_model=d, num_layers=2, num_heads=heads,
         num_kv_heads=kv, d_ff=3 * d,
         vocab_size=tcfg.vocab_size, target_feature_dim=feature_dim(tcfg),
-        gamma=gamma, causal=causal)
+        gamma=gamma, causal=causal, param_dtype=tcfg.param_dtype)
 
 
 def optimizer_for(cfg: ModelConfig) -> OptimizerConfig:

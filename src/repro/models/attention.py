@@ -46,7 +46,6 @@ import jax.numpy as jnp
 
 from repro.models import param as pm
 from repro.models.layers import dense, apply_rope, softcap
-from repro.distributed import compat
 from repro.distributed.sharding import constrain
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -188,9 +187,9 @@ def attend_chunked(q, k, v, *, causal, q_offset, window=None, kv_len=None,
     if vary_axes:
         # inside shard_map with check_vma: scan carries must start with the
         # same varying-manual-axes type as the loop-carried updates
-        m0 = compat.pvary(m0, tuple(vary_axes))
-        l0 = compat.pvary(l0, tuple(vary_axes))
-        a0 = compat.pvary(a0, tuple(vary_axes))
+        m0 = jax.lax.pcast(m0, tuple(vary_axes), to="varying")
+        l0 = jax.lax.pcast(l0, tuple(vary_axes), to="varying")
+        a0 = jax.lax.pcast(a0, tuple(vary_axes), to="varying")
     xs = (kc, vc, jnp.arange(n_chunks)) if em is None else (
         kc, vc, jnp.arange(n_chunks), em)
     (m_f, l_f, acc), _ = jax.lax.scan(body, (m0, l0, a0), xs)

@@ -25,7 +25,11 @@ from repro.distributed.sharding import constrain
 
 
 # ------------------------------------------------------------------ init ---
+@functools.partial(jax.jit, static_argnums=1)
 def lm_init(key, cfg: ModelConfig):
+    """Parameters in ``cfg.param_dtype``. Draws are made in float32 and cast
+    down inside this jit, so XLA fuses each draw into its cast and a
+    full-width model never holds a float32 copy of itself."""
     spec, n_periods, tail = period_spec(cfg)
     ks = pm.split(key, 4 + len(tail))
     p: Dict[str, Any] = {
@@ -44,7 +48,7 @@ def lm_init(key, cfg: ModelConfig):
         p["period"] = period_params
     for i, bs in enumerate(tail):
         p[f"tail{i}"] = block_init(ks[4 + i], cfg, bs)
-    return p
+    return pm.cast_floating(p, cfg.param_dtype)
 
 
 def init_states(cfg: ModelConfig, batch: int, max_len: int, ctx_len: int = 0,

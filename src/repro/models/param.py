@@ -30,3 +30,11 @@ def ones(shape, dtype=jnp.float32):
 
 def split(key, n):
     return list(jax.random.split(key, n))
+
+
+def cast_floating(tree, dtype):
+    """Cast every floating leaf of ``tree`` to ``dtype`` (ints untouched)."""
+    dtype = jnp.dtype(dtype)
+    return jax.tree.map(
+        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+        else a, tree)

@@ -131,8 +131,8 @@ HEADER = """\
 # EXPERIMENTS — D2SD multi-pod JAX framework
 
 Environment: single-CPU container; TPU v5e is the *target* (197 TFLOP/s
-bf16, 819 GB/s HBM, ~50 GB/s/link ICI). Pallas kernels execute under
-`interpret=True`; distribution is proven by lowering + compiling against
+bf16, 819 GB/s HBM, ~50 GB/s/link ICI). Pallas kernels execute in
+interpret mode; distribution is proven by lowering + compiling against
 512 host devices (the multi-pod dry-run). Wall-clock numbers at paper scale
 are therefore **roofline-modeled**; acceptance-length (alpha/TPF) numbers
 are **measured** by running the real engine on trained small-scale models.

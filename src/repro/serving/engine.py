@@ -115,7 +115,9 @@ structure above — allocator free list, refcounts, radix tree, page
 tables, admission accounting, ``_pool_budget`` — is unchanged and counts
 GLOBAL pages, while each page's payload bytes are laid out along the
 ``kv_seq`` mesh axis (``page_size // kv_shards`` slots of every page per
-shard; :func:`~repro.models.kvcache.shard_pool`). Decode's paged
+shard; :func:`~repro.models.kvcache.shard_pool`). The bundle's weights
+are replicated onto every device of the mesh at construction
+(:func:`~repro.distributed.sharding.replicate_onto`). Decode's paged
 cascade verify runs under ``shard_map`` with the per-shard cache
 contribution merged by one float32 LSE ``psum``
 (:func:`~repro.distributed.spdecode.sharded_paged_cache_attend`), so
@@ -256,6 +258,10 @@ class ServingEngine:
                 f"mesh axis size ({self.kv_shards}): page payloads are "
                 f"split WITHIN the page — each shard owns "
                 f"page_size // n_shards slots of every page")
+        if self._mesh is not None:
+            # weights live replicated on every device of the mesh, placed
+            # once here, not moved by every sharded call
+            bundle = sh.replicate_onto(bundle, self._mesh)
         self.bundle = bundle
         self.batch_size = batch_size
         self.early_exit = early_exit

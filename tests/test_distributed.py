@@ -178,8 +178,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.distributed import sharding as sh
 from repro.distributed.pipeline_parallel import pipeline_apply
 
-from repro.distributed.compat import make_mesh
-mesh = make_mesh((4, 2), ("pod", "data"))
+from repro.launch.mesh import mesh_of
+mesh = mesh_of((4, 2), ("pod", "data"))
 S, M, mb, d = 4, 6, 3, 16
 ks = jax.random.split(jax.random.PRNGKey(0), 2)
 w = jax.random.normal(ks[0], (S, d, d)) * 0.3
@@ -209,8 +209,8 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import (compressed_grad_allreduce,
                                            init_error_state)
 
-from repro.distributed.compat import make_mesh, shard_map
-mesh = make_mesh((8,), ("data",))
+from repro.launch.mesh import mesh_of
+mesh = mesh_of((8,), ("data",))
 g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 1000)) \
     * jnp.logspace(-3, 0, 1000)[None]
 true_mean = g_global.mean(0)
@@ -218,7 +218,7 @@ true_mean = g_global.mean(0)
 def step(g_shard, e):
     return compressed_grad_allreduce({"g": g_shard}, {"g": e}, axis="data")
 
-f = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("data"), P("data")),
+f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(P("data"), P("data")),
                       out_specs=(P("data"), P("data")),
                       check_vma=False))
 e = jnp.zeros((8, 1000))

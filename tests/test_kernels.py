@@ -475,3 +475,14 @@ def test_attn_impl_token_parity_serving_ragged():
         eng.run()
         outs[impl] = {r.uid: r.out.tolist() for r in eng.done}
     assert outs["gather"] == outs["pallas"]
+
+
+def test_default_interpret_by_backend(monkeypatch):
+    """Interpret mode on CPU, compiled kernels on TPU, and an error on any
+    other backend instead of a silent interpreter fallback."""
+    assert ops.default_interpret() is True          # the tests run on CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.default_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.default_interpret()

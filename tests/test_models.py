@@ -143,3 +143,26 @@ def test_snap_at_state_advance():
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
         assert int(out["states"]["length"][i]) == n
+
+
+@pytest.mark.parametrize("which", ["target", "drafter"])
+def test_init_honours_param_dtype(which):
+    """lm_init / drafter_init create parameters in ``param_dtype``, equal to
+    the float32 init cast down (the draw happens in float32 either way)."""
+    import dataclasses
+    from repro.core.drafter import DrafterConfig, drafter_init
+    if which == "target":
+        cfg = ModelConfig(num_layers=3, **BASE)
+        init = lm.lm_init
+    else:
+        cfg = DrafterConfig(d_model=64, num_heads=4, num_kv_heads=2,
+                            d_ff=128, vocab_size=97, target_feature_dim=192)
+        init = drafter_init
+    key = jax.random.PRNGKey(7)
+    p32 = init(key, cfg)
+    p16 = init(key, dataclasses.replace(cfg, param_dtype="bfloat16"))
+    assert jax.tree.structure(p32) == jax.tree.structure(p16)
+    for a, b in zip(jax.tree.leaves(p32), jax.tree.leaves(p16)):
+        assert a.dtype == jnp.float32 and b.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.bfloat16)),
+                                      np.asarray(b))

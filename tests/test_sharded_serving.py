@@ -213,6 +213,43 @@ print("sharded drafter read parity ok")
 """)
 
 
+def test_engine_replicates_weights_onto_its_mesh():
+    """An engine built under ``serve.mesh_context`` holds every weight
+    replicated on all mesh devices, with the caller's device-0 buffer as
+    device 0's copy (no second copy there) and the caller's arrays still
+    usable; an engine built without a mesh keeps the bundle as given."""
+    _run(r"""
+import jax, numpy as np
+from conftest import tiny_target, tiny_drafter
+from repro.config.base import SpecConfig
+from repro.launch.serve import mesh_context, random_bundle
+from repro.serving.engine import ServingEngine
+
+assert jax.device_count() == 4, jax.device_count()
+tcfg = tiny_target(vocab=61)
+dcfg = tiny_drafter(vocab=61, gamma=4, target_cfg=tcfg)
+bundle = random_bundle(tcfg, dcfg, SpecConfig(gamma=4, top_k_branches=2))
+assert ServingEngine(bundle, cache_impl="paged", page_size=8).bundle \
+    is bundle
+with mesh_context(1, 4):
+    eng = ServingEngine(bundle, cache_impl="paged", page_size=8)
+dev0 = jax.devices()[0]
+for mine, theirs in zip(jax.tree.leaves(eng.bundle),
+                        jax.tree.leaves(bundle)):
+    assert len(mine.devices()) == 4, mine.sharding
+    assert mine.sharding.is_fully_replicated
+    (home,) = [s for s in mine.addressable_shards if s.device == dev0]
+    assert (home.data.unsafe_buffer_pointer()
+            == theirs.unsafe_buffer_pointer())
+    np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+with mesh_context(1, 4):   # already on the mesh: left as it is
+    again = ServingEngine(eng.bundle, cache_impl="paged", page_size=8)
+assert all(a is b for a, b in zip(jax.tree.leaves(again.bundle),
+                                  jax.tree.leaves(eng.bundle)))
+print("weights replicated ok")
+""", devices=4)
+
+
 def test_pool_invariants_seed0_under_mesh():
     """The tier-1 (seed-0) chunk of the pool/radix/COW invariant suite,
     re-run with every test wrapped in a 1x4 kv_seq mesh context via the
