@@ -109,8 +109,17 @@ def decode_cycle(bundle: SpecBundle, state: EngineState, key,
     # inactive rows (finished requests / idle serving slots) degenerate to
     # a root-only tree: nothing is accepted, nothing is committed below
     draft = strat_lib.mask_inactive(draft, active)
-    vo = backend.verify(bundle, state, draft.tree, draft.dprobs,
-                        draft.max_children, k_verify)
+    with jax.named_scope("d2sd.verify"):
+        vo = backend.verify(bundle, state, draft.tree, draft.dprobs,
+                            draft.max_children, k_verify)
+    with jax.named_scope("d2sd.commit"):
+        return _commit(bundle, state, draft, vo, collect_stats)
+
+
+def _commit(bundle, state, draft, vo, collect_stats):
+    """Feature-cache extension and output assembly of :func:`decode_cycle`
+    after the verify."""
+    active = state.active
     res = vo.res
     tree = draft.tree
 

@@ -241,6 +241,7 @@ def engine_init(bundle, batch: int, max_len: int, ctx_len: int = 0,
     )
 
 
+@jax.named_scope("d2sd.install")
 def prefill(bundle, state: EngineState, prompts, key=None, ctx=None,
             temperature: float = 0.0, true_len=None,
             start=None) -> EngineState:
@@ -469,6 +470,7 @@ def cow_copy_page(state: EngineState, src, dst) -> EngineState:
                              jnp.asarray(dst, jnp.int32))
 
 
+@jax.named_scope("d2sd.install")
 def _install_impl(bundle, state, row, prompt, key, row_table,
                   temperature: float, ctx_len: int, prefix_hit=None,
                   true_len=None, shard_tag=None):
@@ -534,6 +536,7 @@ def install_row(bundle, state: EngineState, row, prompt, key=None,
                                 shard_tag=shard_tag)
 
 
+@jax.named_scope("d2sd.install")
 def _install_rows_impl(bundle, state, rows, prompts, key, row_tables,
                        temperature: float, ctx_len: int, true_len=None,
                        prefix_hits=None, shard_tag=None):

@@ -112,6 +112,7 @@ def mask_inactive(result: DraftResult, active) -> DraftResult:
 
 
 # ----------------------------------------------------- shared draft steps --
+@jax.named_scope("d2sd.draft1")
 def first_draft(bundle, state: EngineState, key, temperature):
     """DFlash pass: returns (trunk [B,g-1], d1_logits [B,g,V])."""
     g = bundle.spec.gamma
@@ -279,41 +280,43 @@ class D2SDStrategy(DraftStrategy):
         b = state.batch
         k1, k3, k4 = jax.random.split(key, 3)
         trunk, d1_logits = first_draft(bundle, state, k1, temp)
-        conf = conf_lib.confidences(d1_logits[:, 1:],
-                                    trunk if temp > 0 else None)
-        r = conf_lib.boundary_posterior(conf)
-        _, fork_idx = conf_lib.topk_prefixes(r, kbr)           # [B, K]
-        branch_tokens, d2_logits = second_draft(
-            bundle.d2_params, bundle.d2_cfg, state.d2_feat,
-            state.anchor, trunk, fork_idx, k3, temp,
-            state.d2_feat["length"])
-        tree = tree_lib.comb_tree(state.anchor, trunk, branch_tokens,
-                                  fork_idx, g)
-        max_children = kbr + 1
-        if spec.third_level:
-            conf2 = conf_lib.confidences(
-                d2_logits[:, :, 1:].reshape(b * kbr, g - 1, -1),
-                branch_tokens.reshape(b * kbr, g - 1) if temp > 0
-                else None).reshape(b, kbr, g - 1)
-            # only suffix slots (> fork) are third-level candidates
-            slot = jnp.arange(1, g)[None, None, :]
-            c2 = jnp.where(slot > fork_idx[:, :, None] + 1, conf2, 1.0)
-            r2 = conf_lib.boundary_posterior(
-                c2.reshape(b * kbr, g - 1)).reshape(b, kbr, g - 1)
-            # r2[..., i] = P(prefix of length i accepted); fork slot = i
-            fork3 = jnp.argmax(r2, axis=-1).astype(jnp.int32)
-            fork3 = jnp.clip(jnp.maximum(fork3, fork_idx + 1), 0, g - 2)
-            # visible prefix for third branches = trunk up to fork_b +
-            # branch b tokens up to fork3_b
-            third_tokens, _ = second_draft(
+        with jax.named_scope("d2sd.select"):
+            conf = conf_lib.confidences(d1_logits[:, 1:],
+                                        trunk if temp > 0 else None)
+            r = conf_lib.boundary_posterior(conf)
+            _, fork_idx = conf_lib.topk_prefixes(r, kbr)           # [B, K]
+        with jax.named_scope("d2sd.draft2"):
+            branch_tokens, d2_logits = second_draft(
                 bundle.d2_params, bundle.d2_cfg, state.d2_feat,
-                state.anchor, _splice(trunk, branch_tokens, fork_idx),
-                fork3, k4, temp, state.d2_feat["length"])
-            tree = tree_lib.extend_third_level(
-                tree, third_tokens, fork_idx, fork3, g)
-            max_children += 1
-        dprobs = (comb_draft_probs(tree, d1_logits, d2_logits, g, temp)
-                  if temp > 0 else None)
+                state.anchor, trunk, fork_idx, k3, temp,
+                state.d2_feat["length"])
+            tree = tree_lib.comb_tree(state.anchor, trunk, branch_tokens,
+                                      fork_idx, g)
+            max_children = kbr + 1
+            if spec.third_level:
+                conf2 = conf_lib.confidences(
+                    d2_logits[:, :, 1:].reshape(b * kbr, g - 1, -1),
+                    branch_tokens.reshape(b * kbr, g - 1) if temp > 0
+                    else None).reshape(b, kbr, g - 1)
+                # only suffix slots (> fork) are third-level candidates
+                slot = jnp.arange(1, g)[None, None, :]
+                c2 = jnp.where(slot > fork_idx[:, :, None] + 1, conf2, 1.0)
+                r2 = conf_lib.boundary_posterior(
+                    c2.reshape(b * kbr, g - 1)).reshape(b, kbr, g - 1)
+                # r2[..., i] = P(prefix of length i accepted); fork slot = i
+                fork3 = jnp.argmax(r2, axis=-1).astype(jnp.int32)
+                fork3 = jnp.clip(jnp.maximum(fork3, fork_idx + 1), 0, g - 2)
+                # visible prefix for third branches = trunk up to fork_b +
+                # branch b tokens up to fork3_b
+                third_tokens, _ = second_draft(
+                    bundle.d2_params, bundle.d2_cfg, state.d2_feat,
+                    state.anchor, _splice(trunk, branch_tokens, fork_idx),
+                    fork3, k4, temp, state.d2_feat["length"])
+                tree = tree_lib.extend_third_level(
+                    tree, third_tokens, fork_idx, fork3, g)
+                max_children += 1
+            dprobs = (comb_draft_probs(tree, d1_logits, d2_logits, g, temp)
+                      if temp > 0 else None)
         return DraftResult(tree=tree, dprobs=dprobs, conf=conf,
                            max_children=max_children)
 

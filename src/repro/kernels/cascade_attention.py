@@ -156,6 +156,7 @@ def cascade_phase1(q, cache_k, cache_v, *, cache_len, q_abs, window=None,
 
     acc, m, l = pl.pallas_call(
         kernel,
+        name="cascade_read_dense",
         grid=(b, hq, n_splits, nk_inner),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -405,6 +406,7 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
     )
     acc, m, l = pl.pallas_call(
         kernel,
+        name="cascade_read_paged",
         grid_spec=grid_spec,
         out_shape=_partial_shapes(b, hq, n_splits, tq, d),
         interpret=interpret,

@@ -126,6 +126,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, q_offset=0, window=None,
     ]
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(b, hq, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -271,6 +272,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, q_offset=0,
         functools.partial(_bwd_dq_kernel, causal=causal, window=window,
                           softcap=attn_softcap, scale=scale, bq=bq, bk=bk,
                           nk=nk),
+        name="flash_attention_bwd_dq",
         grid=(b, hq, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -296,6 +298,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, q_offset=0,
         functools.partial(_bwd_dkv_kernel, causal=causal, window=window,
                           softcap=attn_softcap, scale=scale, bq=bq, bk=bk,
                           nq=nq, g=g),
+        name="flash_attention_bwd_dkv",
         grid=(b, hq, nk, nq),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -153,6 +153,7 @@ from repro.distributed import spdecode
 from repro.models import kvcache as kvc
 from repro.serving.metrics import Clock, MetricsRecorder, MonotonicClock
 from repro.serving.prefix_cache import PrefixCache, PrefixHit
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass
@@ -317,7 +318,11 @@ class ServingEngine:
                       "kv_shards": self.kv_shards,
                       "pool_shard_slots": 0,
                       "decode_collective_bytes": 0,
-                      "warm_cycle_s": 0.0, "warm_cycles": 0}
+                      "warm_cycle_s": 0.0}
+        # host seconds per engine.* span (repro/serving/spans.py), and the
+        # index of the last dispatched cycle, which its spans carry
+        self.span_s: Dict[str, float] = {}
+        self._cycle_no = 0
         self._alpha_num = 0
         self._alpha_den = 0
         self._util_sum = 0.0
@@ -428,118 +433,120 @@ class ServingEngine:
         (everything submitted up front) but starves an open-loop server:
         a wave started at the first arrival would be 1 row wide and
         chain-refill would keep that single row busy forever."""
-        assert self.wave is None, "finish the active wave first"
-        g = self.bundle.spec.gamma
-        if (self.cache_impl == "paged" and self.pool_scope == "engine"
-                and self.pool is None and self.queue):
-            # allocate the engine-lifetime pool ONCE (explicit pool_pages
-            # override, or the engine-global rule over the WHOLE visible
-            # queue — the b largest needs anywhere in it, so a large
-            # request submitted behind small ones still fits when its
-            # turn comes); every later wave borrows the pool, so cached
-            # prefixes survive turnover. Only a request larger than
-            # anything visible at sizing time can fail admission later
-            # (_next_wave raises with guidance).
-            need0 = sorted((self._pages_needed(r, g) for r in self.queue),
-                           reverse=True)
-            b0 = min(self.batch_size, len(self.queue))
-            n_pages = (self._pool_pages_cfg
-                       if self._pool_pages_cfg is not None
-                       else self._pool_budget(need0, b0))
-            self.pool = kvc.PagePool(n_pages, self.page_size)
-            if self.prefix_cache:
-                self.cache = PrefixCache(self.pool)
-        reqs = self._next_wave()
-        if not reqs:
-            return False
-        b = (len(reqs) if width is None
-             else min(self.batch_size, max(width, len(reqs))))
-        # size caches for the wave plus the next batch of likely refill
-        # candidates — not the whole queue, or one huge queued request
-        # would inflate every slot's KV/feature allocation; requests that
-        # don't fit simply wait for the next wave (see _fits)
-        cand = reqs + self.queue[: self.batch_size]
-        cap = max(self._bufs_needed(r, g) for r in cand)
-        pool = None
-        row_pages = None
-        cache = None
-        if self.cache_impl == "paged":
-            # page-granular sizing: the table is as wide as the largest
-            # candidate needs (capped at the pool — no row can ever hold
-            # more), while the POOL is sized by _pool_budget: worst-case
-            # concurrent set + prefix-retention headroom, never a per-
-            # candidate sum. Engine scope reuses the engine pool; wave
-            # scope (legacy A/B reference) builds a fresh one per wave.
-            need = sorted((self._pages_needed(r, g) for r in cand),
-                          reverse=True)
-            if self.pool_scope == "engine":
-                pool, cache = self.pool, self.cache
-            else:
-                pool = kvc.PagePool(self._pool_budget(need, b),
-                                    self.page_size)
+        with span(self.span_s, "engine.start_wave"):
+            assert self.wave is None, "finish the active wave first"
+            g = self.bundle.spec.gamma
+            if (self.cache_impl == "paged" and self.pool_scope == "engine"
+                    and self.pool is None and self.queue):
+                # allocate the engine-lifetime pool ONCE (explicit pool_pages
+                # override, or the engine-global rule over the WHOLE visible
+                # queue — the b largest needs anywhere in it, so a large
+                # request submitted behind small ones still fits when its
+                # turn comes); every later wave borrows the pool, so cached
+                # prefixes survive turnover. Only a request larger than
+                # anything visible at sizing time can fail admission later
+                # (_next_wave raises with guidance).
+                need0 = sorted((self._pages_needed(r, g) for r in self.queue),
+                               reverse=True)
+                b0 = min(self.batch_size, len(self.queue))
+                n_pages = (self._pool_pages_cfg
+                           if self._pool_pages_cfg is not None
+                           else self._pool_budget(need0, b0))
+                self.pool = kvc.PagePool(n_pages, self.page_size)
                 if self.prefix_cache:
-                    cache = PrefixCache(pool)
-            pool_pages = pool.n_pages
-            mp = min(need[0], pool_pages)
-            row_pages = [[] for _ in range(b)]
-            # all rows start unallocated: table rows hold the growth-stable
-            # sentinel until _install patches them
-            table = np.full((b, mp), kvc.PAGE_SENTINEL, np.int32)
-            # borrowed-pool contract: retained device pool buffers (from
-            # capture_pools at the last turnover) go straight into init —
-            # pages the radix tree kept hold their KV across the turnover
-            # and the transient pool-sized zero allocation the old
-            # init-then-adopt_pools sequence paid is never materialized.
-            # Drop our reference: the wave's first donated install
-            # consumes the state. engine_init runs under the mesh scope:
-            # fresh pool buffers are device_put along kv_seq at birth
-            # (adopted buffers pass through untouched — zero-copy).
-            with self._mesh_scope():
-                state = pl.engine_init(self.bundle, b, mp * self.page_size,
-                                       cache_impl="paged",
-                                       page_size=self.page_size,
-                                       pool_pages=pool_pages,
-                                       page_table=table,
-                                       pools=self._pools)
-            self._pools = None
-            # lifetime max, matching pool_peak_pages' scope — a small
-            # leftover wave must not shrink the reported pool below the
-            # peak measured in an earlier, larger wave
-            self.stats["pool_pages"] = max(self.stats["pool_pages"],
-                                           pool_pages)
-            self.stats["pool_shard_slots"] = max(
-                self.stats["pool_shard_slots"],
-                pool_pages * (self.page_size // self.kv_shards))
-        else:
-            max_len = max(self._cache_needed(r, g) for r in cand)
-            with self._mesh_scope():
-                state = pl.engine_init(self.bundle, b, max_len)
-        state = state.replace(active=jnp.zeros((b,), bool))
-        self.wave = Wave(requests=[None] * b, state=state,
-                         bufs=np.zeros((b, cap), np.int32),
-                         filled=np.zeros((b,), np.int64),
-                         targets=np.zeros((b,), np.int64),
-                         t0=self.clock.now(), pool=pool,
-                         row_pages=row_pages,
-                         cache=cache, row_tables=[None] * b,
-                         row_hits=[None] * b, trunc=np.zeros((b,), bool),
-                         evictions0=cache.evictions if cache else 0)
-        # two passes: install EVERY initial request before the first retire.
-        # A retire can chain-refill from beyond the pool-sizing candidate
-        # window; interleaving it with the initial installs could hand those
-        # refills pages the pool only guarantees for the initial set.
-        # Same-bucket initial installs collapse into batched install_rows
-        # calls (one dispatch + one batch-K prefill per length group).
-        self._install_group(list(enumerate(reqs)))
-        for i in range(b):
-            if (self.wave.requests[i] is not None
-                    and self.wave.filled[i] >= self.wave.targets[i]):
-                # satisfied by the prefill alone (max_new <= 1): retire
-                # (and possibly refill) without paying a decode cycle
-                self._retire(i)
-        if self.wave.done:
-            self._finish_wave()
-        return True
+                    self.cache = PrefixCache(self.pool)
+            reqs = self._next_wave()
+            if not reqs:
+                return False
+            b = (len(reqs) if width is None
+                 else min(self.batch_size, max(width, len(reqs))))
+            # size caches for the wave plus the next batch of likely refill
+            # candidates — not the whole queue, or one huge queued request
+            # would inflate every slot's KV/feature allocation; requests that
+            # don't fit simply wait for the next wave (see _fits)
+            cand = reqs + self.queue[: self.batch_size]
+            cap = max(self._bufs_needed(r, g) for r in cand)
+            pool = None
+            row_pages = None
+            cache = None
+            if self.cache_impl == "paged":
+                # page-granular sizing: the table is as wide as the largest
+                # candidate needs (capped at the pool — no row can ever hold
+                # more), while the POOL is sized by _pool_budget: worst-case
+                # concurrent set + prefix-retention headroom, never a per-
+                # candidate sum. Engine scope reuses the engine pool; wave
+                # scope (legacy A/B reference) builds a fresh one per wave.
+                need = sorted((self._pages_needed(r, g) for r in cand),
+                              reverse=True)
+                if self.pool_scope == "engine":
+                    pool, cache = self.pool, self.cache
+                else:
+                    pool = kvc.PagePool(self._pool_budget(need, b),
+                                        self.page_size)
+                    if self.prefix_cache:
+                        cache = PrefixCache(pool)
+                pool_pages = pool.n_pages
+                mp = min(need[0], pool_pages)
+                row_pages = [[] for _ in range(b)]
+                # all rows start unallocated: table rows hold the growth-stable
+                # sentinel until _install patches them
+                table = np.full((b, mp), kvc.PAGE_SENTINEL, np.int32)
+                # borrowed-pool contract: retained device pool buffers (from
+                # capture_pools at the last turnover) go straight into init —
+                # pages the radix tree kept hold their KV across the turnover
+                # and the transient pool-sized zero allocation the old
+                # init-then-adopt_pools sequence paid is never materialized.
+                # Drop our reference: the wave's first donated install
+                # consumes the state. engine_init runs under the mesh scope:
+                # fresh pool buffers are device_put along kv_seq at birth
+                # (adopted buffers pass through untouched — zero-copy).
+                with self._mesh_scope():
+                    state = pl.engine_init(self.bundle, b, mp * self.page_size,
+                                           cache_impl="paged",
+                                           page_size=self.page_size,
+                                           pool_pages=pool_pages,
+                                           page_table=table,
+                                           pools=self._pools)
+                self._pools = None
+                # lifetime max, matching pool_peak_pages' scope — a small
+                # leftover wave must not shrink the reported pool below the
+                # peak measured in an earlier, larger wave
+                self.stats["pool_pages"] = max(self.stats["pool_pages"],
+                                               pool_pages)
+                self.stats["pool_shard_slots"] = max(
+                    self.stats["pool_shard_slots"],
+                    pool_pages * (self.page_size // self.kv_shards))
+            else:
+                max_len = max(self._cache_needed(r, g) for r in cand)
+                with self._mesh_scope():
+                    state = pl.engine_init(self.bundle, b, max_len)
+            state = state.replace(active=jnp.zeros((b,), bool))
+            self.wave = Wave(requests=[None] * b, state=state,
+                             bufs=np.zeros((b, cap), np.int32),
+                             filled=np.zeros((b,), np.int64),
+                             targets=np.zeros((b,), np.int64),
+                             t0=self.clock.now(), pool=pool,
+                             row_pages=row_pages,
+                             cache=cache, row_tables=[None] * b,
+                             row_hits=[None] * b, trunc=np.zeros((b,), bool),
+                             evictions0=cache.evictions if cache else 0)
+            # two passes: install EVERY initial request before the first
+            # retire. A retire can chain-refill from beyond the pool-sizing
+            # candidate window; interleaving it with the initial installs
+            # could hand those refills pages the pool only guarantees for
+            # the initial set. Same-bucket initial installs collapse into
+            # batched install_rows calls (one dispatch + one batch-K
+            # prefill per length group).
+            self._install_group(list(enumerate(reqs)))
+            for i in range(b):
+                if (self.wave.requests[i] is not None
+                        and self.wave.filled[i] >= self.wave.targets[i]):
+                    # satisfied by the prefill alone (max_new <= 1): retire
+                    # (and possibly refill) without paying a decode cycle
+                    self._retire(i)
+            if self.wave.done:
+                self._finish_wave()
+            return True
 
     def _bucket(self, n: int) -> int:
         """Pad a prefill length to its bucket (identity when disabled)."""
@@ -623,45 +630,47 @@ class ServingEngine:
         only the uncached suffix is prefilled. ``prefix_len`` short-
         circuits the prep when :meth:`_install_group` already ran it.
         """
-        w = self.wave
-        self.key, sub = jax.random.split(self.key)
-        if prefix_len is None:
-            prefix_len = self._prep_install(slot, r)
-        hit = w.row_hits[slot] if w.row_hits is not None else None
-        row_table = (w.row_tables[slot] if self.cache_impl == "paged"
-                     else None)
-        prompt = np.asarray(r.prompt, np.int32)
-        suffix = prompt[prefix_len:]
-        s = len(suffix)
-        true_len = None
-        if self.bucket_sizes is not None:
-            pad = self._bucket(s)
-            suffix = np.concatenate(
-                [suffix, np.zeros((pad - s,), np.int32)])
-            true_len = s
-        # full donated-install trace key: suffix shape + warm/cold + the
-        # wave geometry the state shapes derive from (a new wave with a
-        # different batch / capacity / pool size retraces even for an
-        # already-seen suffix length)
-        self._install_shapes.add(
-            (1, len(suffix), hit is not None, w.state.batch, w.state.max_len,
-             w.pool.n_pages if w.pool is not None else 0))
-        self.stats["install_traces"] = len(self._install_shapes)
-        self.stats["refill_copy_bytes"] += refill_copy_bytes(w.state, s)
-        self.stats["installs"] += 1
-        self.stats["install_calls"] += 1
-        if self.recorder is not None:
-            self.recorder.on_admit(r.uid)
-        with self._mesh_scope():
-            w.state = install_row(self.bundle, w.state, slot, suffix,
-                                  key=sub,
-                                  temperature=self.bundle.spec.temperature,
-                                  row_table=row_table,
-                                  prefix_hit=prefix_len if hit else None,
-                                  true_len=true_len,
-                                  shard_tag=self._shard_tag)
-        self.clock.tick("install")
-        self._book_install(slot, r)
+        with span(self.span_s, "engine.install", uid=r.uid):
+            w = self.wave
+            self.key, sub = jax.random.split(self.key)
+            if prefix_len is None:
+                prefix_len = self._prep_install(slot, r)
+            hit = w.row_hits[slot] if w.row_hits is not None else None
+            row_table = (w.row_tables[slot] if self.cache_impl == "paged"
+                         else None)
+            prompt = np.asarray(r.prompt, np.int32)
+            suffix = prompt[prefix_len:]
+            s = len(suffix)
+            true_len = None
+            if self.bucket_sizes is not None:
+                pad = self._bucket(s)
+                suffix = np.concatenate(
+                    [suffix, np.zeros((pad - s,), np.int32)])
+                true_len = s
+            # full donated-install trace key: suffix shape + warm/cold + the
+            # wave geometry the state shapes derive from (a new wave with a
+            # different batch / capacity / pool size retraces even for an
+            # already-seen suffix length)
+            self._install_shapes.add(
+                (1, len(suffix), hit is not None, w.state.batch,
+                 w.state.max_len,
+                 w.pool.n_pages if w.pool is not None else 0))
+            self.stats["install_traces"] = len(self._install_shapes)
+            self.stats["refill_copy_bytes"] += refill_copy_bytes(w.state, s)
+            self.stats["installs"] += 1
+            self.stats["install_calls"] += 1
+            if self.recorder is not None:
+                self.recorder.on_admit(r.uid)
+            with self._mesh_scope():
+                w.state = install_row(self.bundle, w.state, slot, suffix,
+                                      key=sub,
+                                      temperature=self.bundle.spec.temperature,
+                                      row_table=row_table,
+                                      prefix_hit=prefix_len if hit else None,
+                                      true_len=true_len,
+                                      shard_tag=self._shard_tag)
+            self.clock.tick("install")
+            self._book_install(slot, r)
 
     def _book_install(self, slot: int, r: Request) -> None:
         """Host bookkeeping shared by single and batched installs. The
@@ -669,7 +678,8 @@ class ServingEngine:
         install's prefill) is NOT read back here — reading it would block
         the host on the device stream and kill install/decode overlap.
         The slot is marked pending and the anchor lands in ``bufs`` at the
-        next retire boundary (:meth:`_flush_anchors`)."""
+        next retire boundary (:meth:`_flush_anchors`), which also stamps
+        the first token."""
         w = self.wave
         w.bufs[slot] = 0
         w.pending_anchor.add(slot)
@@ -679,10 +689,6 @@ class ServingEngine:
         w.trunc[slot] = False
         r.t_start = self.clock.now()
         r.n_cycles = 0
-        if self.recorder is not None:
-            # first token exists once the dispatched install completes —
-            # stamped here at dispatch, after charging the install tick
-            self.recorder.on_first_token(r.uid)
 
     def _flush_anchors(self) -> None:
         """Materialize pending install anchors into ``bufs``.
@@ -690,13 +696,18 @@ class ServingEngine:
         The single deferred host read of the overlap design: called before
         a cycle dispatch consumes (donates) the state, and at retire
         boundaries before banked outputs are assembled. One blocking
-        ``np.asarray`` covers every install since the last flush."""
+        ``np.asarray`` covers every install since the last flush. A
+        request's first token reaches the host here, and is stamped
+        (``MetricsRecorder.on_first_token``) here."""
         w = self.wave
         if w is None or not w.pending_anchor:
             return
-        anchors = np.asarray(w.state.anchor)
-        for slot in w.pending_anchor:
+        with span(self.span_s, "engine.flush_anchors"):
+            anchors = np.asarray(w.state.anchor)
+        for slot in sorted(w.pending_anchor):
             w.bufs[slot, 0] = int(anchors[slot])
+            if self.recorder is not None:
+                self.recorder.on_first_token(w.requests[slot].uid)
         w.pending_anchor.clear()
 
     def _install_group(self, picks: List[Tuple[int, Request]]) -> None:
@@ -740,45 +751,46 @@ class ServingEngine:
         """One donated batch-K install for K same-suffix-bucket requests
         (already prepped by :meth:`_prep_install`; all cold or all warm —
         warm rows may carry different prefix lengths)."""
-        w = self.wave
-        self.key, sub = jax.random.split(self.key)
-        k = len(grp)
-        row_tables = None
-        if self.cache_impl == "paged":
-            row_tables = np.stack([w.row_tables[slot]
-                                   for slot, _, _ in grp])
-        prompts = np.zeros((k, pad), np.int32)
-        true = np.zeros((k,), np.int32)
-        pfx = np.zeros((k,), np.int32)
-        for i, (slot, r, p0) in enumerate(grp):
-            sfx = np.asarray(r.prompt, np.int32)[p0:]
-            prompts[i, : len(sfx)] = sfx
-            true[i] = len(sfx)
-            pfx[i] = p0
-            self.stats["refill_copy_bytes"] += refill_copy_bytes(
-                w.state, len(sfx))
-            if self.recorder is not None:
-                self.recorder.on_admit(r.uid)
-        self._install_shapes.add(
-            (k, pad, warm, w.state.batch, w.state.max_len,
-             w.pool.n_pages if w.pool is not None else 0))
-        self.stats["install_traces"] = len(self._install_shapes)
-        self.stats["installs"] += k
-        self.stats["install_calls"] += 1
-        true_len = true if self.bucket_sizes is not None else None
-        with self._mesh_scope():
-            w.state = install_rows(self.bundle, w.state,
-                                   np.array([s for s, _, _ in grp],
-                                            np.int32),
-                                   prompts, key=sub,
-                                   temperature=self.bundle.spec.temperature,
-                                   row_tables=row_tables, true_len=true_len,
-                                   prefix_hits=pfx if warm else None,
-                                   shard_tag=self._shard_tag)
-        # ONE dispatch for the whole group: one simulated install charge
-        self.clock.tick("install")
-        for slot, r, _ in grp:
-            self._book_install(slot, r)
+        with span(self.span_s, "engine.install", uid=grp[0][1].uid,
+                  rows=len(grp)):
+            w = self.wave
+            self.key, sub = jax.random.split(self.key)
+            k = len(grp)
+            row_tables = None
+            if self.cache_impl == "paged":
+                row_tables = np.stack([w.row_tables[slot]
+                                       for slot, _, _ in grp])
+            prompts = np.zeros((k, pad), np.int32)
+            true = np.zeros((k,), np.int32)
+            pfx = np.zeros((k,), np.int32)
+            for i, (slot, r, p0) in enumerate(grp):
+                sfx = np.asarray(r.prompt, np.int32)[p0:]
+                prompts[i, : len(sfx)] = sfx
+                true[i] = len(sfx)
+                pfx[i] = p0
+                self.stats["refill_copy_bytes"] += refill_copy_bytes(
+                    w.state, len(sfx))
+                if self.recorder is not None:
+                    self.recorder.on_admit(r.uid)
+            self._install_shapes.add(
+                (k, pad, warm, w.state.batch, w.state.max_len,
+                 w.pool.n_pages if w.pool is not None else 0))
+            self.stats["install_traces"] = len(self._install_shapes)
+            self.stats["installs"] += k
+            self.stats["install_calls"] += 1
+            true_len = true if self.bucket_sizes is not None else None
+            with self._mesh_scope():
+                w.state = install_rows(
+                    self.bundle, w.state,
+                    np.array([s for s, _, _ in grp], np.int32), prompts,
+                    key=sub, temperature=self.bundle.spec.temperature,
+                    row_tables=row_tables, true_len=true_len,
+                    prefix_hits=pfx if warm else None,
+                    shard_tag=self._shard_tag)
+            # ONE dispatch for the whole group: one simulated install charge
+            self.clock.tick("install")
+            for slot, r, _ in grp:
+                self._book_install(slot, r)
 
     # ---- sizing: single source of truth for allocation and admission ----
     @staticmethod
@@ -836,34 +848,39 @@ class ServingEngine:
         w = self.wave
         if w is None:
             return None
-        self._flush_anchors()
-        b = len(w.requests)
-        active = self._host_active()
-        # push the mask: with early_exit, finished/idle rows cost nothing
-        # and commit nothing; without it they keep running full cycles
-        # (legacy behavior, kept for A/B benchmarking)
-        w.state = w.state.replace(
-            active=jnp.asarray(active) if self.early_exit
-            else jnp.ones((b,), bool))
-        self.key, sub = jax.random.split(self.key)
-        n0 = len(spdecode.PAYLOAD_TRACE)
-        with self._mesh_scope():
-            w.state, out = self._cycle(w.state, sub)
-        if len(spdecode.PAYLOAD_TRACE) > n0:
-            # a fresh decode trace under a mesh just recorded the bytes
-            # its verify LSE-merge collectives move per cycle (one entry
-            # per sharded paged-attend layer); bank the per-cycle sum
-            self._cycle_payload = sum(spdecode.PAYLOAD_TRACE[n0:])
-        self.stats["decode_collective_bytes"] += self._cycle_payload
-        w.cycles += 1
-        self.clock.tick("cycle")
-        if w.pool is not None:
-            self._util_sum += w.pool.pages_in_use / max(w.pool.n_pages, 1)
-            self._util_samples += 1
-        # stats: only rows that were actively serving a request count
-        # toward acceptance; the rest are wasted batch capacity
-        self.stats["wasted_row_cycles"] += int(b - active.sum())
-        return active, out, self.clock.now()
+        self._cycle_no += 1
+        with span(self.span_s, "engine.dispatch_cycle", cycle=self._cycle_no):
+            self._flush_anchors()
+            b = len(w.requests)
+            with span(self.span_s, "engine.prepare"):
+                active = self._host_active()
+                # push the mask: with early_exit, finished/idle rows cost
+                # nothing and commit nothing; without it they keep running
+                # full cycles (legacy behavior, kept for A/B benchmarking)
+                w.state = w.state.replace(
+                    active=jnp.asarray(active) if self.early_exit
+                    else jnp.ones((b,), bool))
+                self.key, sub = jax.random.split(self.key)
+            n0 = len(spdecode.PAYLOAD_TRACE)
+            with span(self.span_s, "engine.enqueue"):
+                with self._mesh_scope():
+                    w.state, out = self._cycle(w.state, sub)
+            if len(spdecode.PAYLOAD_TRACE) > n0:
+                # a fresh decode trace under a mesh just recorded the bytes
+                # its verify LSE-merge collectives move per cycle (one
+                # entry per sharded paged-attend layer); bank the sum
+                self._cycle_payload = sum(spdecode.PAYLOAD_TRACE[n0:])
+            self.stats["decode_collective_bytes"] += self._cycle_payload
+            w.cycles += 1
+            self.clock.tick("cycle")
+            if w.pool is not None:
+                self._util_sum += (w.pool.pages_in_use
+                                   / max(w.pool.n_pages, 1))
+                self._util_samples += 1
+            # stats: only rows that were actively serving a request count
+            # toward acceptance; the rest are wasted batch capacity
+            self.stats["wasted_row_cycles"] += int(b - active.sum())
+        return active, out, self.clock.now(), self._cycle_no
 
     def complete_cycle(self, handle) -> bool:
         """Block on a dispatched cycle's results, bank tokens, retire.
@@ -879,9 +896,27 @@ class ServingEngine:
         w = self.wave
         if handle is None or w is None:
             return False
-        active, out, t_disp = handle
-        toks = np.asarray(out["tokens"])            # retire-boundary sync
-        n_out = np.asarray(out["n_out"])
+        active, out, t_disp, cyc = handle
+        with span(self.span_s, "engine.complete_cycle", cycle=cyc):
+            with span(self.span_s, "engine.readback"):
+                toks = np.asarray(out["tokens"])    # retire-boundary sync
+                n_out = np.asarray(out["n_out"])
+                n_acc = np.asarray(out["n_acc"])
+            with span(self.span_s, "engine.bank"):
+                finished = self._bank(active, toks, n_out, n_acc, t_disp)
+            for i in finished:
+                with span(self.span_s, "engine.retire",
+                          uid=w.requests[i].uid):
+                    self._retire(i)
+            if w.done:
+                self._finish_wave()
+                return False
+        return True
+
+    def _bank(self, active, toks, n_out, n_acc, t_disp) -> List[int]:
+        """Bank a completed cycle's tokens into the wave's streams; returns
+        the slots whose request is finished."""
+        w = self.wave
         if w.cycles > 1:
             # steady-state sample: the wave's first cycle carries the
             # trace/compile cost and is excluded (wall_s still counts it)
@@ -890,7 +925,8 @@ class ServingEngine:
         self._alpha_num += int(n_out[active].sum())
         self._alpha_den += int(active.sum())
         # real accepted-draft counts straight from the verify backends
-        self.stats["accepted"] += int(np.asarray(out["n_acc"])[active].sum())
+        self.stats["accepted"] += int(n_acc[active].sum())
+        finished = []
         for i in range(len(w.requests)):
             r = w.requests[i]
             if r is None:
@@ -907,11 +943,8 @@ class ServingEngine:
                 w.filled[i] = min(w.filled[i] + int(n_out[i]), cap)
                 r.n_cycles += 1
             if w.filled[i] >= w.targets[i] or r.n_cycles > r.max_new + 8:
-                self._retire(i)
-        if w.done:
-            self._finish_wave()
-            return False
-        return True
+                finished.append(i)
+        return finished
 
     def step(self) -> bool:
         """Run ONE decode cycle synchronously (dispatch + complete
@@ -937,33 +970,34 @@ class ServingEngine:
         at dispatch) and installs touch only that row + freshly allocated
         pages. Returns the number of requests admitted.
         """
-        w = self.wave
-        if w is None or not self.refill or not self.queue:
-            return 0
-        g = self.bundle.spec.gamma
-        picks: List[Tuple[int, Request]] = []
-        reserved = 0
-        for slot in range(len(w.requests)):
-            if w.requests[slot] is not None:
-                continue
-            if not self.queue or not self._fits(self.queue[0], reserved):
-                break
-            r = self.queue.pop(0)
-            picks.append((slot, r))
-            if self.cache_impl == "paged":
-                # reserve against concurrent picks: _fits sees the pool
-                # before these installs allocate their pages
-                reserved += self._pages_needed(r, g)
-        if not picks:
-            return 0
-        self._install_group(picks)
-        self.stats["refills"] += len(picks)
-        for slot, r in picks:
-            if w.requests[slot] is not None \
-                    and w.filled[slot] >= w.targets[slot]:
-                # satisfied by the prefill alone (max_new <= 1)
-                self._retire(slot)
-        return len(picks)
+        with span(self.span_s, "engine.admit_idle"):
+            w = self.wave
+            if w is None or not self.refill or not self.queue:
+                return 0
+            g = self.bundle.spec.gamma
+            picks: List[Tuple[int, Request]] = []
+            reserved = 0
+            for slot in range(len(w.requests)):
+                if w.requests[slot] is not None:
+                    continue
+                if not self.queue or not self._fits(self.queue[0], reserved):
+                    break
+                r = self.queue.pop(0)
+                picks.append((slot, r))
+                if self.cache_impl == "paged":
+                    # reserve against concurrent picks: _fits sees the pool
+                    # before these installs allocate their pages
+                    reserved += self._pages_needed(r, g)
+            if not picks:
+                return 0
+            self._install_group(picks)
+            self.stats["refills"] += len(picks)
+            for slot, r in picks:
+                if w.requests[slot] is not None \
+                        and w.filled[slot] >= w.targets[slot]:
+                    # satisfied by the prefill alone (max_new <= 1)
+                    self._retire(slot)
+            return len(picks)
 
     def _retire(self, slot: int) -> None:
         w = self.wave
@@ -1033,7 +1067,6 @@ class ServingEngine:
                                if self._alpha_den else 0.0)
         if self._warm_durs:
             self.stats["warm_cycle_s"] = float(np.median(self._warm_durs))
-            self.stats["warm_cycles"] = len(self._warm_durs)
         if w.pool is not None:
             self.stats["pool_peak_pages"] = max(
                 self.stats["pool_peak_pages"], w.pool.peak_in_use)

@@ -111,8 +111,9 @@ class VirtualClock(Clock):
 class RequestTiming:
     """The four lifecycle timestamps of one request + derived SLA terms.
 
-    ``t_first`` is the time the request's FIRST generated token exists —
-    the prefill's anchor token, stamped when the install is dispatched.
+    ``t_first`` is the time the request's FIRST generated token (the
+    prefill's anchor) reaches the host: the engine stamps it when it reads
+    the anchor back, not when it dispatches the install.
     """
     uid: int
     t_arrival: Optional[float] = None

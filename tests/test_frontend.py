@@ -334,3 +334,28 @@ def test_start_wave_width_builds_idle_rows(bundle):
     while eng.wave is not None:
         eng.step()
     assert len(eng.done) == 2
+
+
+def test_first_token_is_stamped_when_its_anchor_reaches_the_host(bundle):
+    """The recorder's first token is the install's anchor read back on
+    the host, not the install's dispatch: two installs charged 0.25 each
+    on the virtual clock are both stamped at the first read-back (0.5),
+    and a mid-flight install only when the next cycle's dispatch reads
+    its anchor back."""
+    eng = _engine(bundle, batch=3)
+    rec = eng.recorder
+    rng = np.random.default_rng(5)
+    for n in (6, 12):                       # buckets 8 and 16: two installs
+        eng.submit(rng.integers(3, VOCAB, size=n).astype(np.int32),
+                   max_new=6)
+    eng.start_wave(width=eng.batch_size)
+    assert eng.clock.now() == 0.5
+    assert [rec.requests[u].t_first for u in (0, 1)] == [None, None]
+    handle = eng.dispatch_cycle()           # reads both anchors back
+    assert [rec.requests[u].t_first for u in (0, 1)] == [0.5, 0.5]
+    eng.submit(rng.integers(3, VOCAB, size=6).astype(np.int32), max_new=6)
+    assert eng.admit_idle() == 1            # installed at 1.5 + 0.25
+    assert rec.requests[2].t_first is None
+    eng.complete_cycle(handle)
+    eng.dispatch_cycle()
+    assert rec.requests[2].t_first == 1.75
