@@ -25,9 +25,11 @@ page table rides in as a scalar-prefetch operand
 (``pltpu.PrefetchScalarGridSpec``) so the K/V BlockSpec ``index_map``
 resolves each grid step's LOGICAL page to its PHYSICAL pool page before
 the DMA is issued — the kernel streams exactly the row's pages out of HBM
-with no gather materialization, keeping the same split-K grid and
-ragged/sliding-window masking as the dense kernel (logical key positions
-are unchanged; only the addressing is indirected).
+with no gather materialization, keeping the split-K partials and
+ragged/sliding-window masking of the dense kernel (logical key positions
+are unchanged; only the addressing is indirected). Its grid runs over KV
+heads, not query heads: each page is loaded once for the query heads that
+share it, and steps past a row's live pages skip the update.
 """
 from __future__ import annotations
 
@@ -255,15 +257,23 @@ def _phase1_paged_kernel(pt_ref, cache_len_ref, off_ref,   # scalar prefetch
                          q_abs_ref, q_ref, k_ref, v_ref,      # VMEM blocks
                          acc_ref, m_ref, l_ref,               # outputs
                          racc, rm, rl,                        # scratch
-                         *, page, pos_stride, nk_inner, tq, window, softcap,
-                         scale):
-    """Identical flash accumulation to ``_phase1_kernel`` with one KV page
-    per inner step. The physical page was already resolved by the BlockSpec
-    index_map (scalar-prefetched page table), so the body only deals in
-    LOGICAL key positions: page ``s*nk_inner + jj`` holds positions
-    [base, base+page). Unallocated logical pages surface garbage from a
-    clamped pool page and die on the ``kpos < cache_len`` mask, exactly
-    like the dense kernel's tail padding.
+                         *, g, page, pos_stride, nk_inner, tq, window,
+                         softcap, scale):
+    """``_phase1_kernel``'s flash accumulation with one KV page per inner
+    step, for all ``g`` query heads that share the step's KV head: their
+    rows come stacked as one ``[g*tq, D]`` block, so the page is loaded
+    once and each product is one dot. The physical page was already
+    resolved by the BlockSpec index_map (scalar-prefetched page table), so
+    the body only deals in LOGICAL key positions: page
+    ``step = s*nk_inner + jj`` holds positions [base, base+page).
+
+    A step past the row's live pages (``ceil(cache_len / pos_stride)``,
+    the count ``kv_map`` clamps to) does no work: its page would only be
+    masked dead, and a split that is dead throughout writes the init state
+    (``m = -1e30``, ``l = 0``, ``acc = 0``), which the LSE merge weights to
+    zero. Garbage a live page surfaces past ``cache_len`` dies on the
+    ``kpos < cache_len`` mask, exactly like the dense kernel's tail
+    padding.
 
     ``pos_stride``/``off_ref`` decouple logical positions from the local
     page extent: logical page ``i`` of this buffer covers absolute
@@ -271,10 +281,13 @@ def _phase1_paged_kernel(pt_ref, cache_len_ref, off_ref,   # scalar prefetch
     single-device engine uses the identity (stride == page, off == 0);
     a kv_seq shard whose pages hold slots ``[ax*page_loc, (ax+1)*page_loc)``
     of every GLOBAL page passes stride=global page size, off=ax*page_loc.
+    The live count ignores the offset, so a shard may run a step whose
+    slots all lie past ``cache_len``, and the mask kills it.
     """
     b = pl.program_id(0)
     s = pl.program_id(2)       # split index
     jj = pl.program_id(3)      # inner page step within the split
+    step = s * nk_inner + jj
 
     @pl.when(jj == 0)
     def _init():
@@ -282,37 +295,49 @@ def _phase1_paged_kernel(pt_ref, cache_len_ref, off_ref,   # scalar prefetch
         rm[...] = jnp.full_like(rm, NEG_INF)
         rl[...] = jnp.zeros_like(rl)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale              # [tq, D]
-    k = k_ref[0, 0].astype(jnp.float32)                      # [page, D]
-    v = v_ref[0, 0].astype(jnp.float32)
-
-    sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [tq, page]
-    if softcap is not None:
-        sc = softcap * jnp.tanh(sc / softcap)
-
     clen = cache_len_ref[b]
-    base = (s * nk_inner + jj) * pos_stride + off_ref[0]
-    kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)  # [1, page]
-    qp = q_abs_ref[0]                                        # [tq, 1]
-    ok = (kpos < clen) & (kpos <= qp)
-    if window is not None:
-        ok &= kpos > (qp - window)
-    sc = jnp.where(ok, sc, NEG_INF)
 
-    m_prev = rm[...]                                         # [tq, 1]
-    m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
-    p = jnp.exp(sc - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    rl[...] = rl[...] * alpha + p.sum(axis=1, keepdims=True)
-    racc[...] = racc[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
-    rm[...] = m_new
+    @pl.when(step < (clen + pos_stride - 1) // pos_stride)
+    def _update():
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # [g*tq, D]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [page, D]
+        v = v_ref[0, 0].astype(jnp.float32)
+
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        if softcap is not None:
+            sc = softcap * jnp.tanh(sc / softcap)
+
+        base = step * pos_stride + off_ref[0]
+        kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        qp = q_abs_ref[0]                                    # [g*tq, 1]
+        ok = (kpos < clen) & (kpos <= qp)
+        if window is not None:
+            ok &= kpos > (qp - window)
+        sc = jnp.where(ok, sc, NEG_INF)
+
+        m_prev = rm[...]                                     # [g*tq, 1]
+        m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        rl[...] = rl[...] * alpha + p.sum(axis=1, keepdims=True)
+        racc[...] = racc[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())))
+        rm[...] = m_new
 
     @pl.when(jj == nk_inner - 1)
     def _final():
-        acc_ref[0, 0, 0] = racc[...]
-        m_ref[0, 0, 0] = rm[...]
-        l_ref[0, 0, 0] = rl[...]
+        for h in range(g):
+            rows = slice(h * tq, (h + 1) * tq)
+            acc_ref[0, h, 0] = racc[rows]
+            m_ref[0, h, 0] = rm[rows]
+            l_ref[0, h, 0] = rl[rows]
+
+
+def paged_table_width(max_pages: int, n_splits: int = 8) -> int:
+    """Table width :func:`cascade_phase1_paged` walks: ``max_pages`` padded
+    up to a multiple of the split count ``min(n_splits, max_pages)``."""
+    ns = max(1, min(n_splits, max_pages))
+    return max_pages + (-max_pages) % ns
 
 
 def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
@@ -323,18 +348,22 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
 
     q [B,Hq,Tq,D]; pools [P,Hkv,page,D]; page_table [B,max_pages] physical
     page ids (out-of-range entries = unallocated; they are clamped for the
-    DMA and masked by ``cache_len``). One grid step streams one page; the
-    table is a scalar-prefetch operand so the index_map can address pages
-    data-dependently — the TPU analogue of paged attention's block table.
-    Returns flash partials acc [B,Hq,ns,Tq,D], m/l [B,Hq,ns,Tq].
+    DMA and masked by ``cache_len``). The grid is (B, Hkv, splits, pages
+    per split): one step reads one page of one KV head for the ``Hq/Hkv``
+    query heads that share it, stacked into one ``[Hq/Hkv * Tq, D]``
+    block. The table is a scalar-prefetch operand so
+    the index_map can address pages data-dependently — the TPU analogue
+    of paged attention's block table. Returns flash partials
+    acc [B,Hq,ns,Tq,D], m/l [B,Hq,ns,Tq]; head ``h`` reads KV head
+    ``h // (Hq/Hkv)``.
 
-    Bytes scale with LIVE length, not capacity: the index_map clamps the
-    logical page step to the row's last live page (``cache_len`` is also a
-    scalar-prefetch operand, so it is available at index time). Pallas
-    elides the DMA when consecutive grid steps resolve to the same block
-    index, so the dead tail of the table costs compute on a resident page
-    but no additional HBM traffic — the body's ``kpos < cache_len`` mask,
-    which works off the UNclamped logical step, still zeroes those scores.
+    Work scales with LIVE length, not capacity: ``cache_len`` is also a
+    scalar-prefetch operand, so the index_map clamps the logical page step
+    to the row's last live page, and Pallas elides the DMA when
+    consecutive grid steps resolve to the same block index; the body skips
+    those steps outright. A dead table entry still costs one grid step of
+    pipeline overhead; only a loop over live pages inside the body would
+    remove it.
 
     ``pos_stride`` (static; default = pool page extent) and ``pos_offset``
     (traced scalar; default 0) place logical page ``i`` at absolute
@@ -348,18 +377,17 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
     g = hq // hkv
     mp = page_table.shape[-1]
     scale = scale if scale is not None else d ** -0.5
-    n_splits = max(1, min(n_splits, mp))
     # keep the requested split count by padding the TABLE (not the pool)
     # with sentinel pages — mirrors the dense kernel's cache padding, so a
     # prime max_pages does not collapse the split-K parallelism. Padded
-    # pages clamp to the last physical page and die on the kpos<cache_len
-    # mask (their logical positions start at mp*page >= any cache_len).
-    pad = (-mp) % n_splits
+    # pages lie past every live page, so the body skips them.
+    n_splits = max(1, min(n_splits, mp))
+    width = paged_table_width(mp, n_splits)
     page_table = jnp.asarray(page_table, jnp.int32).reshape(-1, mp)
-    if pad:
-        page_table = jnp.pad(page_table, ((0, 0), (0, pad)),
+    if width > mp:
+        page_table = jnp.pad(page_table, ((0, 0), (0, width - mp)),
                              constant_values=n_phys)
-        mp = mp + pad
+        mp = width
     nk_inner = mp // n_splits
 
     if pos_stride is None:
@@ -367,42 +395,45 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
     pt = jnp.minimum(page_table, n_phys - 1).reshape(-1)      # [B*MP]
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
-    qa = _q_abs_col(q_abs, b, tq)
+    # query head h*g + i is rows [i*tq, (i+1)*tq) of KV head h's stacked
+    # block, and every head's rows carry the same per-query positions
+    qs = q.reshape(b, hkv, g * tq, d)
+    qa = jnp.tile(_q_abs_col(q_abs, b, tq), (1, g, 1))       # [B, g*tq, 1]
     off = jnp.asarray(0 if pos_offset is None else pos_offset,
                       jnp.int32).reshape(-1)[:1]
 
     kernel = functools.partial(
-        _phase1_paged_kernel, page=page, pos_stride=pos_stride,
+        _phase1_paged_kernel, g=g, page=page, pos_stride=pos_stride,
         nk_inner=nk_inner, tq=tq, window=window, softcap=attn_softcap,
         scale=scale)
 
-    def kv_map(b_, h, s, j, pt_ref, clen_ref, off_ref, g=g,
-               nki=nk_inner, mp=mp, stride=pos_stride):
+    def kv_map(b_, h, s, j, pt_ref, clen_ref, off_ref, nki=nk_inner, mp=mp,
+               stride=pos_stride):
         # Clamp the logical step to the last LIVE page: Pallas elides the
         # DMA when the resolved block index repeats across grid steps, so
-        # the dead tail of the table moves no extra bytes. The body masks
-        # off the duplicated page's scores via the unclamped kpos.
+        # the dead tail of the table moves no extra bytes (and the body
+        # skips it).
         step = s * nki + j
         live = (clen_ref[b_] + stride - 1) // stride
         step = jnp.minimum(step, jnp.maximum(live - 1, 0))
-        return (pt_ref[b_ * mp + step], h // g, 0, 0)
+        return (pt_ref[b_ * mp + step], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hq, n_splits, nk_inner),
+        grid=(b, hkv, n_splits, nk_inner),
         in_specs=[
-            pl.BlockSpec((1, tq, 1),
+            pl.BlockSpec((1, g * tq, 1),
                          lambda b_, h, s, j, pt_, cl_, off_: (b_, 0, 0)),
-            pl.BlockSpec((1, 1, tq, d),
+            pl.BlockSpec((1, 1, g * tq, d),
                          lambda b_, h, s, j, pt_, cl_, off_: (b_, h, 0, 0)),
             pl.BlockSpec((1, 1, page, d), kv_map),
             pl.BlockSpec((1, 1, page, d), kv_map),
         ],
-        out_specs=[pl.BlockSpec((1, 1, 1, tq, n),
+        out_specs=[pl.BlockSpec((1, g, 1, tq, n),
                                 lambda b_, h, s, j, pt_, cl_, off_:
                                 (b_, h, s, 0, 0))
                    for n in (d, 1, 1)],
-        scratch_shapes=_partial_scratch(tq, d),
+        scratch_shapes=_partial_scratch(g * tq, d),
     )
     acc, m, l = pl.pallas_call(
         kernel,
@@ -410,7 +441,7 @@ def cascade_phase1_paged(q, pool_k, pool_v, page_table, *, cache_len, q_abs,
         grid_spec=grid_spec,
         out_shape=_partial_shapes(b, hq, n_splits, tq, d),
         interpret=interpret,
-    )(pt, clen, off, qa, q, pool_k, pool_v)
+    )(pt, clen, off, qa, qs, pool_k, pool_v)
     return acc, m[..., 0], l[..., 0]
 
 

@@ -117,9 +117,11 @@ def cascade_attention_paged(q, pool_k, pool_v, page_table, blk_k, blk_v, *,
     ``page_table`` [B, max_pages]: physical page of each logical page
     (out-of-range sentinel entries mark unallocated pages). The page table
     is scalar-prefetched so the Pallas kernel DMAs pages straight from the
-    pool — no dense gather of the logical view, and the index_map clamps
-    dead logical pages to the last live one so HBM traffic scales with
-    ``cache_len``, not table capacity. ``pos_stride``/``pos_offset``
+    pool — no dense gather of the logical view. One grid step reads one
+    page of one KV head for every query head sharing it; the index_map
+    clamps dead logical pages to the last live one and the body skips
+    them, so HBM traffic and compute scale with ``cache_len``, while each
+    dead table entry still costs one grid step. ``pos_stride``/``pos_offset``
     relocate logical page ``i`` to absolute positions
     ``i*pos_stride + pos_offset + [0, page)`` for kv_seq-sharded pools
     (see ``cascade_attention.cascade_phase1_paged``).

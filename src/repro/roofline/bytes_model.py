@@ -7,7 +7,11 @@ dense logical view, written back, and re-read by attention regardless of
 how much of the cache is live), while the kernel path's traffic scales
 with *live* length (the page-table index_map clamps dead logical pages to
 the last live one, and Pallas elides repeated-block DMAs — see
-``kernels/cascade_attention.cascade_phase1_paged``).
+``kernels/cascade_attention.cascade_phase1_paged``). Bytes are all this
+model prices: the kernel takes one grid step per table entry, and a dead
+entry's step moves no bytes and does no math but still costs the
+pipeline's per-step overhead, so device time does not follow these bytes
+alone.
 
 This module prices both paths from config + geometry alone so the serving
 bench can emit an attributable ``bytes_model`` section; the companion HLO
@@ -28,9 +32,9 @@ Counting rules (deliberately simple, stated so the numbers are auditable):
   ``attend_cache_plus_block``.
 * "pallas" (paged global layers): ceil(live / page_size) page-sized DMA
   streams per layer — live-length traffic, rounded up to page
-  granularity. Per-kv-head-group revisits and split-K re-streaming are
-  hardware-scheduling details the model ignores on both paths (they
-  multiply both sides equally at fixed geometry).
+  granularity (the kernel reads each live page once per KV head for all
+  the query heads that share it). Split-K re-streaming is a
+  hardware-scheduling detail the model ignores on both paths.
 * ROLLING local layers (dense window-capped buffers, both cache impls):
   "gather" reads the rolling buffer, materializes the [cache; block]
   concat, and re-reads it in attention = 3x window-capped capacity per

@@ -102,7 +102,11 @@ only and ``accepted`` draft tokens wired from the verify backends'
 ``n_acc``, ``wasted_row_cycles``, the KV-memory counters
 (``refill_copy_bytes`` — accounting model of bytes written per install,
 :func:`~repro.core.state.refill_copy_bytes` — plus ``pool_pages`` /
-``pool_peak_pages`` and the per-cycle mean ``pool_utilization``), and the
+``pool_peak_pages`` and the per-cycle mean ``pool_utilization``), the
+paged verify read's page steps summed over cycles (``read_live_pages``:
+``ceil(cache_len / page_size)`` per active row; ``read_table_pages``:
+active rows x the table width the kernel walks — their ratio is the
+share of its page steps that do work), and the
 prefix-cache counters (``prefix_hits`` / ``prefix_misses`` /
 ``prefix_hit_tokens`` / ``prefill_tokens_saved`` / ``cow_copies`` /
 ``prefix_evictions``).
@@ -150,6 +154,7 @@ from repro.core.state import (EngineState, capture_pools, cow_copy_page,
                               install_row, install_rows, refill_copy_bytes)
 from repro.distributed import sharding as sh
 from repro.distributed import spdecode
+from repro.kernels import cascade_attention as casc
 from repro.models import kvcache as kvc
 from repro.serving.metrics import Clock, MetricsRecorder, MonotonicClock
 from repro.serving.prefix_cache import PrefixCache, PrefixHit
@@ -318,6 +323,7 @@ class ServingEngine:
                       "kv_shards": self.kv_shards,
                       "pool_shard_slots": 0,
                       "decode_collective_bytes": 0,
+                      "read_live_pages": 0, "read_table_pages": 0,
                       "warm_cycle_s": 0.0}
         # host seconds per engine.* span (repro/serving/spans.py), and the
         # index of the last dispatched cycle, which its spans carry
@@ -877,6 +883,13 @@ class ServingEngine:
                 self._util_sum += (w.pool.pages_in_use
                                    / max(w.pool.n_pages, 1))
                 self._util_samples += 1
+                rows = np.flatnonzero(active)
+                lens = np.array([len(w.requests[i].prompt) + w.filled[i] - 1
+                                 for i in rows], np.int64)
+                self.stats["read_live_pages"] += int(
+                    (-(-lens // self.page_size)).sum())
+                self.stats["read_table_pages"] += len(rows) * (
+                    casc.paged_table_width(w.state.max_pages))
             # stats: only rows that were actively serving a request count
             # toward acceptance; the rest are wasted batch capacity
             self.stats["wasted_row_cycles"] += int(b - active.sum())

@@ -237,7 +237,7 @@ def test_cascade_phase1_split_count_invariant():
 
 
 PAGED_CASES = [
-    # (B, Hq, Hkv, Tq, page, mp, n_phys, cache_lens, window)
+    # (B, Hq, Hkv, Tq, page, mp, n_phys, cache_lens, window[, n_splits=4])
     (2, 4, 2, 12, 64, 8, 20, (512, 256), None),     # page-aligned
     (2, 4, 2, 12, 64, 8, 20, (505, 250), None),     # page-straddling
     (2, 4, 2, 12, 64, 8, 20, (505, 131), 100),      # straddling + window
@@ -245,6 +245,13 @@ PAGED_CASES = [
     (3, 2, 2, 8, 32, 6, 24, (192, 100, 65), 64),    # 3-way ragged + window
     (2, 4, 2, 8, 64, 7, 15, (410, 230), None),      # PRIME max_pages:
     # the table pads to keep 4-way split-K instead of collapsing to 1
+    # GQA 8 at the verify's 76 tree nodes; 57 pages pad to 64 over 8
+    # splits, and both rows end before split 2: whole splits are dead
+    (2, 16, 2, 76, 64, 57, 120, (700, 1023), None, 8),
+    # GQA 4 with one row shorter than a page (its splits past 0 are dead)
+    (2, 8, 2, 16, 64, 8, 20, (37, 300), None),
+    # ragged 3 rows, each ending part-way into a split
+    (3, 8, 4, 12, 32, 12, 40, (100, 230, 5), None),
 ]
 
 
@@ -253,7 +260,8 @@ def test_cascade_paged_matches_ref(case):
     """Paged cascade kernel (scalar-prefetch page-table index_map) vs the
     gather-then-dense oracle, over shuffled disjoint page tables with
     unallocated sentinel tails."""
-    b, hq, hkv, tq, page, mp, n_phys, cache_lens, window = case
+    b, hq, hkv, tq, page, mp, n_phys, cache_lens, window = case[:9]
+    n_splits = case[9] if len(case) > 9 else 4
     d = 64
     rng = np.random.default_rng(hash(case) % 2 ** 31)
     ks = jax.random.split(jax.random.PRNGKey(hash(case) % 2 ** 31), 5)
@@ -274,7 +282,7 @@ def test_cascade_paged_matches_ref(case):
     tree_mask = jnp.tril(jnp.ones((tq, tq), bool))
     o = ops.cascade_attention_paged(
         q, pk, pv, jnp.asarray(pt), bk, bv, cache_len=cache_len,
-        q_abs=q_abs, tree_mask=tree_mask, window=window, n_splits=4,
+        q_abs=q_abs, tree_mask=tree_mask, window=window, n_splits=n_splits,
         interpret=True, layout="BHTD")
     o_ref = ref.cascade_attention_paged_ref(
         q, pk, pv, jnp.asarray(pt), bk, bv, cache_len=cache_len,

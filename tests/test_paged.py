@@ -364,3 +364,31 @@ def test_serving_paged_pool_reuse_across_retires(bundle):
             bundle.target_params, bundle.target_cfg,
             jnp.asarray(prompts[r.uid])[None], r.max_new))[0]
         assert np.array_equal(r.out, ref), r.uid
+
+
+def test_serving_paged_read_page_counters(bundle):
+    """``read_live_pages`` / ``read_table_pages`` count, per dispatched
+    cycle, the live pages of each active row's committed cache (read off
+    the device state) and the table width the paged read walks."""
+    from repro.kernels.cascade_attention import paged_table_width
+    prompts, wants = _traffic(bundle.target_cfg.vocab_size)
+    eng = ServingEngine(bundle, batch_size=2, cache_impl="paged",
+                        page_size=PAGE)
+    for p, n in zip(prompts, wants):
+        eng.submit(p, max_new=n)
+    eng.start_wave(width=2)
+    live = table = cycles = 0
+    while eng.wave is not None and cycles < 6:
+        w = eng.wave
+        eng._flush_anchors()
+        active = eng._host_active()
+        lens = np.asarray(w.state.length)[active]
+        live += int(sum(-(-int(n) // PAGE) for n in lens))
+        table += int(active.sum()) * paged_table_width(w.state.max_pages)
+        handle = eng.dispatch_cycle()
+        eng.admit_idle()
+        eng.complete_cycle(handle)
+        cycles += 1
+        assert eng.stats["read_live_pages"] == live
+        assert eng.stats["read_table_pages"] == table
+    assert cycles == 6 and 0 < live < table
