@@ -8,8 +8,8 @@ traffic through the timed path, exactly as ``bench/run.py`` runs them,
 then the run's check: the widest gap of the served tokens against the
 reference over every request with tokens when the window closes; on the
 control seeds also the widest gap of the tokens that the fp8 control
-puts first at the same positions (``bench/reference.py``). Prints one
-JSON line per seed.
+puts first at the same positions (the cell's reference module, as
+``bench/spec.py`` resolves it). Prints one JSON line per seed.
 """
 import argparse
 import json

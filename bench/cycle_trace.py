@@ -9,9 +9,11 @@ from ``bench/xplane.py`` carries each operation's name stack under
 the operations inside it, never their sum: the "XLA Ops" line nests a
 ``while`` over the operations of its body.
 
-The paged read is found by its kernel's name (``pallas_call(name=...)``
+The verify's read is found by its kernels' names (``pallas_call(name=...)``
 becomes the instruction's name), which the benchmark's own trace holds
-too, so :func:`verify_read` needs no scopes.
+too, so :func:`verify_read` needs no scopes: ``cascade_read_paged`` over
+the page pool of a global layer, and ``cascade_read_dense`` over the
+rolling buffer of a sliding-window layer (no dense decoder has one).
 """
 from __future__ import annotations
 
@@ -26,6 +28,8 @@ PHASES = ("d2sd.draft1", "d2sd.select", "d2sd.draft2", "d2sd.verify",
 DRAFT = PHASES[:3]
 CYCLE_PROGRAM = "decode_cycle"
 KERNEL = "cascade_read_paged"
+DENSE_KERNEL = "cascade_read_dense"
+READ_KERNELS = (KERNEL, DENSE_KERNEL)
 TREE_NODES = model.GAMMA + model.TOP_K * (model.GAMMA - 1)
 
 # "%cascade_read_paged.13 = (f32[B,Hq,splits,T,D]{...}, ...) custom-call("
@@ -52,7 +56,7 @@ def cycles(trace, lo: float, hi: float) -> int:
 
 def phase_ms(trace, lo: float, hi: float) -> Optional[Dict[str, float]]:
     """Device ms per decode cycle of each phase, of the drafts together
-    (``draft``), of the verify's paged read (``kernel``), of the
+    (``draft``), of the verify's cascade read (``kernel``), of the
     decode-cycle program (``cycle``) and of its time in no phase
     (``unattributed``). None without scopes or cycles."""
     n = cycles(trace, lo, hi)
@@ -63,7 +67,8 @@ def phase_ms(trace, lo: float, hi: float) -> Optional[Dict[str, float]]:
            for p in PHASES}
     out["draft"] = trace_reduce.busy_ns(scoped(trace, DRAFT), lo, hi) * per
     kern = [e for e, st in zip(trace["ops"], trace["scopes"])
-            if _kernel(e[0]) == KERNEL and _in_scope(st, ("d2sd.verify",))]
+            if _kernel(e[0]) in READ_KERNELS
+            and _in_scope(st, ("d2sd.verify",))]
     out["kernel"] = trace_reduce.busy_ns(kern, lo, hi) * per
     out["cycle"] = trace_reduce.matching(
         trace["modules"], (CYCLE_PROGRAM,), lo, hi)[0] * per
@@ -80,14 +85,15 @@ def _kernel(op_name: str) -> Optional[str]:
 
 def verify_read(ops: Iterable[Event], heads: int, lo: float,
                 hi: float) -> Tuple[float, int]:
-    """(device ns, calls) of the tree verify's paged cascade read in
-    [lo, hi): the ``cascade_read_paged`` calls whose partials have the
-    target's ``heads`` and the tree's node count (the drafters' calls of
-    the same kernel read their own heads for 16 or 64 block slots)."""
+    """(device ns, calls) of the tree verify's cascade read in [lo, hi):
+    the calls of :data:`READ_KERNELS`, one per attention layer and cycle,
+    whose partials have the target's ``heads`` and the tree's node count
+    (the drafters' calls of the same kernels read their own heads for 16
+    or 64 block slots)."""
     ns, n = 0.0, 0
     for name, s, d in ops:
         m = _PARTIALS.match(name)
-        if (m and m.group(1) == KERNEL and lo <= s < hi
+        if (m and m.group(1) in READ_KERNELS and lo <= s < hi
                 and int(m.group(3)) == heads
                 and int(m.group(4)) == TREE_NODES):
             ns += d
@@ -97,14 +103,15 @@ def verify_read(ops: Iterable[Event], heads: int, lo: float,
 
 def read_work(arch, lens: Sequence[int]) -> Tuple[float, float]:
     """(FLOPs, HBM bytes) that the tree verify's read of the live context
-    needs in one cycle, whatever implements it: for each layer and each
-    active row of context ``ctx``, QK^T and PV of the tree's nodes against
-    every context token, 4 * Hq * D * T * ctx FLOPs, and one read of that
-    context's bf16 keys and values, 2 * Hkv * D * 2 * ctx bytes."""
-    ctx = float(sum(lens))
-    flops = 4.0 * arch.heads * arch.head_dim * TREE_NODES * ctx
-    nbytes = 2.0 * arch.kv_heads * arch.head_dim * 2 * ctx
-    return arch.layers * flops, arch.layers * nbytes
+    needs in one cycle, whatever implements it: for each active row of
+    context ``ctx``, over the ``arch.attended(ctx)`` tokens its attention
+    layers read, QK^T and PV of the tree's nodes against each token,
+    4 * Hq * D * T FLOPs, and one read of its bf16 key and value,
+    2 * Hkv * D * 2 bytes."""
+    att = float(sum(arch.attended(n) for n in lens))
+    flops = 4.0 * arch.heads * arch.head_dim * TREE_NODES * att
+    nbytes = 2.0 * arch.kv_heads * arch.head_dim * 2 * att
+    return flops, nbytes
 
 
 def read_least_s(arch, lens: Sequence[int], peak: Dict) -> float:

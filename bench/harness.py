@@ -4,18 +4,29 @@ comparison that decides ``correct``.
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up (counted in ``setup_s``): draw the weights on the device from the
-seed, build the engine with the options of the cell's mix, warm every
-program shape the cell's traffic reaches (``warm_up``), then bring the
-closed batch to its steady state: every request queued, the first batch
-installed part-way through its outputs (``bench/traffic.py``), one cycle
-run. The window then runs ``--seconds`` of the same loop. With
+seed through the cell's architecture module, build the engine with the
+options of the cell's mix, warm every program shape the cell's traffic
+reaches (``warm_up``), then bring the closed batch to its steady state:
+every request queued, the first batch installed part-way through its
+outputs (``bench/traffic.py``), one cycle run. The window then runs
+``--seconds`` of the same loop. With
 ``--trace 1`` the last ``trace_s`` seconds of the window are traced and
 the run reports its per-layer metrics instead of its end-to-end ones.
 
 When the window closes, the tokens served so far to every request (the
 rows still running and those that finished) are kept, the engine is
-dropped, and the plain reference (``bench/reference.py``) runs over each
-of them.
+dropped, and the plain reference runs over each of them.
+
+The architecture is the cell's (``spec.Cell.model`` and
+``spec.Cell.reference``, resolved from the configuration file by
+``bench/spec.py``): ``bench/model.py`` and ``bench/reference.py`` for a
+dense decoder, ``bench/archs/<name>.py`` and
+``bench/archs/<name>_reference.py`` for one the configuration names.
+The architecture module provides ``Arch.from_file``,
+``program_configs``, ``make_weights`` and ``bundle``; the reference
+module provides ``tail_logits``. The comparison that decides ``correct``
+(``reference.gaps``, the limits of the configuration file and the mix's
+``check_tokens_min``) is the same for every architecture.
 """
 from __future__ import annotations
 
@@ -118,12 +129,14 @@ class Bench:
 
     def __init__(self, cell: spec.Cell, seed: int):
         self.seed, self.mix = seed, cell.mix
-        self.arch = model.Arch.from_file(cell.config_file)
+        arch_mod = cell.model
+        self.tail_logits = cell.reference.tail_logits
+        self.arch = arch_mod.Arch.from_file(cell.config_file)
         conf = json.loads(cell.config_file.read_text())
         self.limits = conf["bench"].get("limits", {})
-        _, dcfg, _ = model.program_configs(self.arch)
-        self.w, d1, d2 = model.make_weights(seed, self.arch, dcfg)
-        self.bundle = model.bundle(self.arch, self.w, d1, d2)
+        _, dcfg, _ = arch_mod.program_configs(self.arch)
+        self.w, d1, d2 = arch_mod.make_weights(seed, self.arch, dcfg)
+        self.bundle = arch_mod.bundle(self.arch, self.w, d1, d2)
         mix = self.mix
         self.ladder = tuple(mix["buckets"])
         self.eng = PinnedEngine(
@@ -229,11 +242,11 @@ class Bench:
         gc.collect()
 
     def check(self, control: bool = False) -> Dict:
-        """Run the plain reference over every kept request (its prompt and
-        served tokens); the widest gap of the served tokens, the number
-        checked and, with ``control``, the widest gap of the tokens the
-        fp8 control puts first at the same positions."""
-        res = [reference.gaps(self.w, self.arch, p, s,
+        """Run the cell's plain reference over every kept request (its
+        prompt and served tokens); the widest gap of the served tokens, the
+        number checked and, with ``control``, the widest gap of the tokens
+        the fp8 control puts first at the same positions."""
+        res = [reference.gaps(self.tail_logits, self.w, self.arch, p, s,
                               t_len=reference.seq_len(len(p) + len(s)),
                               n_out=reference.out_len(len(s)),
                               control=control)
@@ -331,6 +344,25 @@ def window_log(b: Bench, rec: Dict, n_compiles: int, s_compiles: float,
         f"gc_s_in_window={s_gc:.3f}")
 
 
+def verdict(b: Bench, got: Dict):
+    """(``correct``, the numbers compared, each beside its limit) of a
+    check (:meth:`Bench.check`): the widest gap against the configuration
+    file's limit and the tokens checked against the mix's least number;
+    the same for every architecture."""
+    checks = {
+        "widest_gap": {"value": got["widest_gap"],
+                       "limit": b.limits.get("widest_gap", 0.0)},
+        "tokens_checked": {"value": got["tokens_checked"],
+                           "limit": b.mix["check_tokens_min"]},
+    }
+    correct = bool(got["finite"]
+                   and checks["widest_gap"]["value"]
+                   <= checks["widest_gap"]["limit"]
+                   and checks["tokens_checked"]["value"]
+                   >= checks["tokens_checked"]["limit"])
+    return correct, checks
+
+
 def main(argv, t_proc0: float, root: Path, require_tpu: bool = True,
          fault=None) -> Dict:
     """Run one cell; prints the checks on stderr and returns the result
@@ -401,17 +433,7 @@ def main(argv, t_proc0: float, root: Path, require_tpu: bool = True,
     got = b.check()
     log(f"reference: {got['requests_checked']} requests, "
         f"{got['tokens_checked']} tokens in {time.perf_counter() - t0:.3f}s")
-    checks = {
-        "widest_gap": {"value": got["widest_gap"],
-                       "limit": b.limits.get("widest_gap", 0.0)},
-        "tokens_checked": {"value": got["tokens_checked"],
-                           "limit": b.mix["check_tokens_min"]},
-    }
-    correct = bool(got["finite"]
-                   and checks["widest_gap"]["value"]
-                   <= checks["widest_gap"]["limit"]
-                   and checks["tokens_checked"]["value"]
-                   >= checks["tokens_checked"]["limit"])
+    correct, checks = verdict(b, got)
     for name, c in checks.items():
         log(f"check {name}: {c['value']} (limit {c['limit']})")
     result = {"correct": correct, "attempted": attempted, "failed": 0,

@@ -1,5 +1,31 @@
-"""A configuration file -> the served model: program configs, and weights
-drawn on the device from the seed.
+"""The dense architecture module: a configuration file -> the served model
+(program configs, and weights drawn on the device from the seed).
+
+A configuration file names its architecture in its ``bench`` block
+(``"model": "<name>"``); without that key this module and
+``bench/reference.py`` serve it. A named architecture is
+``bench/archs/<name>.py`` with its plain forward in
+``bench/archs/<name>_reference.py``, both loaded by path
+(``bench/spec.py``), so a later PR adds one as new files. An architecture
+module provides what this one does:
+
+* ``Arch.from_file(path)``: the sizes, hashable (a static argument of
+  jitted calls), with ``vocab``, ``layers``, ``heads``, ``kv_heads`` and
+  ``head_dim``; ``Arch.matmul_params()`` and ``Arch.attended(ctx)``, the
+  two quantities the per-layer readers need (``mfu``,
+  ``cascade_read_roofline``);
+* ``program_configs(arch)``: (target ModelConfig, DrafterConfig,
+  SpecConfig) of the program;
+* ``make_weights(seed, arch, dcfg)``: (target weights in the benchmark's
+  layout, drafter-1 params, drafter-2 params), on the device;
+* ``bundle(arch, w, d1, d2)``: the program's ``SpecBundle`` over them.
+
+The comparison that decides ``correct`` is shared by every architecture
+(``bench/reference.py``). ``GAMMA`` and ``TOP_K`` are the engine's
+geometry, not the architecture's. :func:`target_config`,
+:func:`spec_configs`, :func:`normal`, :func:`layer_stack`,
+:func:`draw_weights`, :func:`seed_key` and :func:`block_tree` are pieces
+another architecture module can reuse.
 
 The weights are the benchmark's own. :func:`make_weights` draws the
 target in the benchmark's layout (``bench/reference.py`` reads that
@@ -65,13 +91,17 @@ class Arch:
         per_layer = self.d * (q + 2 * kv) + q * self.d + 3 * self.d * self.ff
         return self.layers * per_layer + self.d * self.vocab
 
+    def attended(self, ctx: int) -> int:
+        """Tokens the attention layers read at context ``ctx``, summed over
+        layers: every layer attends to the whole context."""
+        return self.layers * ctx
 
-def program_configs(arch: Arch):
-    """(target ModelConfig, DrafterConfig, SpecConfig) of the program,
-    reading the KV cache through the Pallas cascade kernels."""
-    from repro.config.base import Family, ModelConfig, SpecConfig
-    from repro.launch.steps import production_drafter
-    tcfg = ModelConfig(
+
+def target_config(arch: Arch):
+    """The program's ModelConfig of the dense target, reading the KV cache
+    through the Pallas cascade kernels."""
+    from repro.config.base import Family, ModelConfig
+    return ModelConfig(
         name="bench-target", family=Family.DENSE, num_layers=arch.layers,
         d_model=arch.d, num_heads=arch.heads, num_kv_heads=arch.kv_heads,
         head_dim=arch.head_dim, d_ff=arch.ff, vocab_size=arch.vocab,
@@ -79,6 +109,13 @@ def program_configs(arch: Arch):
         rope_theta=arch.theta, norm_eps=arch.eps,
         tie_embeddings=arch.tied, max_seq_len=32768, remat=False,
         dtype=arch.dtype, param_dtype=arch.dtype, attn_impl="pallas")
+
+
+def spec_configs(tcfg):
+    """(tcfg, DrafterConfig, SpecConfig): the benchmark's drafters and
+    greedy D2SD cycle for the target ``tcfg``."""
+    from repro.config.base import SpecConfig
+    from repro.launch.steps import production_drafter
     dcfg = dataclasses.replace(production_drafter(tcfg, GAMMA),
                                attn_impl="pallas")
     spec = SpecConfig(gamma=GAMMA, top_k_branches=TOP_K, mode="d2sd",
@@ -86,49 +123,68 @@ def program_configs(arch: Arch):
     return tcfg, dcfg, spec
 
 
-def _normal(key, shape, std):
+def program_configs(arch: Arch):
+    """(target ModelConfig, DrafterConfig, SpecConfig) of the program."""
+    return spec_configs(target_config(arch))
+
+
+def normal(key, shape, std):
+    """A float32 draw of ``shape`` from a normal of ``std`` cut at two
+    standard deviations."""
     return jax.random.truncated_normal(key, -2.0, 2.0, shape,
                                        jnp.float32) * std
+
+
+def layer_stack(ks, n: int, arch: Arch) -> Dict[str, Any]:
+    """``n`` dense layers stacked on axis 0, each weight drawn from the
+    next key of the iterator ``ks`` (nine, three more with q/k/v biases,
+    two more with qk_norm)."""
+    d, ff = arch.d, arch.ff
+    q, kv = arch.heads * arch.head_dim, arch.kv_heads * arch.head_dim
+    lay = {
+        "attn_norm": 1.0 + normal(next(ks), (n, d), 0.1),
+        "wq": normal(next(ks), (n, d, q), d ** -0.5),
+        "wk": normal(next(ks), (n, d, kv), d ** -0.5),
+        "wv": normal(next(ks), (n, d, kv), d ** -0.5),
+        "wo": normal(next(ks), (n, q, d), q ** -0.5),
+        "mlp_norm": 1.0 + normal(next(ks), (n, d), 0.1),
+        "w_gate": normal(next(ks), (n, d, ff), d ** -0.5),
+        "w_up": normal(next(ks), (n, d, ff), d ** -0.5),
+        "w_down": normal(next(ks), (n, ff, d), ff ** -0.5),
+    }
+    if arch.qkv_bias:
+        lay["bq"] = normal(next(ks), (n, q), 0.1)
+        lay["bk"] = normal(next(ks), (n, kv), 0.1)
+        lay["bv"] = normal(next(ks), (n, kv), 0.1)
+    if arch.qk_norm:
+        lay["q_norm"] = 1.0 + normal(next(ks), (n, arch.head_dim), 0.1)
+        lay["k_norm"] = 1.0 + normal(next(ks), (n, arch.head_dim), 0.1)
+    return lay
 
 
 def _target_weights(key, arch: Arch) -> Dict[str, Any]:
     """The target in the benchmark's layout (layers stacked on axis 0),
     drawn in float32 and cast inside the caller's jit."""
-    L, d, ff = arch.layers, arch.d, arch.ff
-    q, kv = arch.heads * arch.head_dim, arch.kv_heads * arch.head_dim
     ks = iter(jax.random.split(key, 20))
-    lay = {
-        "attn_norm": 1.0 + _normal(next(ks), (L, d), 0.1),
-        "wq": _normal(next(ks), (L, d, q), d ** -0.5),
-        "wk": _normal(next(ks), (L, d, kv), d ** -0.5),
-        "wv": _normal(next(ks), (L, d, kv), d ** -0.5),
-        "wo": _normal(next(ks), (L, q, d), q ** -0.5),
-        "mlp_norm": 1.0 + _normal(next(ks), (L, d), 0.1),
-        "w_gate": _normal(next(ks), (L, d, ff), d ** -0.5),
-        "w_up": _normal(next(ks), (L, d, ff), d ** -0.5),
-        "w_down": _normal(next(ks), (L, ff, d), ff ** -0.5),
-    }
-    if arch.qkv_bias:
-        lay["bq"] = _normal(next(ks), (L, q), 0.1)
-        lay["bk"] = _normal(next(ks), (L, kv), 0.1)
-        lay["bv"] = _normal(next(ks), (L, kv), 0.1)
-    if arch.qk_norm:
-        lay["q_norm"] = 1.0 + _normal(next(ks), (L, arch.head_dim), 0.1)
-        lay["k_norm"] = 1.0 + _normal(next(ks), (L, arch.head_dim), 0.1)
-    w = {"embed": _normal(next(ks), (arch.vocab, d), 0.02),
-         "final_norm": 1.0 + _normal(next(ks), (d,), 0.1),
+    lay = layer_stack(ks, arch.layers, arch)
+    w = {"embed": normal(next(ks), (arch.vocab, arch.d), 0.02),
+         "final_norm": 1.0 + normal(next(ks), (arch.d,), 0.1),
          "layers": lay}
     if not arch.tied:
-        w["head"] = _normal(next(ks), (d, arch.vocab), 0.02)
+        w["head"] = normal(next(ks), (arch.d, arch.vocab), 0.02)
     return w
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _draw(key, arch: Arch, dcfg):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def draw_weights(key, arch, dcfg, target_weights):
+    """(target, drafter-1 params, drafter-2 params) from ``key`` in one
+    jitted call: the target drawn by ``target_weights(key, arch)`` in
+    float32 and cast to ``arch.dtype``, the drafters by the program's
+    initializer."""
     from repro.core.drafter import drafter_init
     k_t, k_1, k_2 = jax.random.split(key, 3)
     dt = jnp.dtype(arch.dtype)
-    w = jax.tree.map(lambda a: a.astype(dt), _target_weights(k_t, arch))
+    w = jax.tree.map(lambda a: a.astype(dt), target_weights(k_t, arch))
     return w, drafter_init(k_1, dcfg), drafter_init(k_2, dcfg)
 
 
@@ -143,25 +199,30 @@ def seed_key(seed: int):
 def make_weights(seed: int, arch: Arch, dcfg):
     """(target weights, drafter-1 params, drafter-2 params), on the
     default device, from ``seed``."""
-    return _draw(seed_key(seed), arch, dcfg)
+    return draw_weights(seed_key(seed), arch, dcfg, _target_weights)
 
 
-def target_tree(w: Dict[str, Any], arch: Arch) -> Dict[str, Any]:
-    """The program's target parameter tree over the same arrays."""
-    lay = w["layers"]
+def block_tree(lay: Dict[str, Any], arch: Arch) -> Dict[str, Any]:
+    """The program's parameters of one stacked block over the arrays of a
+    :func:`layer_stack`."""
     attn = {"wq": lay["wq"], "wk": lay["wk"], "wv": lay["wv"],
             "wo": lay["wo"]}
     if arch.qkv_bias:
         attn.update(bq=lay["bq"], bk=lay["bk"], bv=lay["bv"])
     if arch.qk_norm:
         attn.update(q_norm=lay["q_norm"], k_norm=lay["k_norm"])
-    block = {"ln1": {"scale": lay["attn_norm"]}, "attn": attn,
-             "ln2": {"scale": lay["mlp_norm"]},
-             "ffn": {"w_in": lay["w_up"], "w_gate": lay["w_gate"],
-                     "w_out": lay["w_down"]}}
+    return {"ln1": {"scale": lay["attn_norm"]}, "attn": attn,
+            "ln2": {"scale": lay["mlp_norm"]},
+            "ffn": {"w_in": lay["w_up"], "w_gate": lay["w_gate"],
+                    "w_out": lay["w_down"]}}
+
+
+def target_tree(w: Dict[str, Any], arch: Arch) -> Dict[str, Any]:
+    """The program's target parameter tree over the same arrays: one
+    period of one block, stacked over every layer."""
     p = {"tok": {"embedding": w["embed"]},
          "ln_f": {"scale": w["final_norm"]},
-         "period": {"p0": block}}
+         "period": {"p0": block_tree(w["layers"], arch)}}
     if not arch.tied:
         p["lm_head"] = w["head"]
     return p

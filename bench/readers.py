@@ -20,10 +20,11 @@ def program_ms_per(run, patterns: Sequence[str]) -> Optional[float]:
 
 def target_flops(run, cycles) -> float:
     """FLOPs of the plain target forward for the tokens ``cycles``
-    committed: per token, 2 x the multiplied parameters plus QK^T and PV
-    over the token's context."""
+    committed: per token, 2 x the multiplied parameters
+    (``Arch.matmul_params``) plus QK^T and PV over the tokens its
+    attention layers read at its context (``Arch.attended``)."""
     a = run.arch
     per_tok = 2.0 * a.matmul_params()
-    attn = 4.0 * a.layers * a.heads * a.head_dim
-    return sum(n * (per_tok + attn * ln)
+    attn = 4.0 * a.heads * a.head_dim
+    return sum(n * (per_tok + attn * a.attended(ln))
                for c in cycles for n, ln in zip(c.n_out, c.lens))

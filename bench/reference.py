@@ -1,25 +1,38 @@
-"""Plain reference of the served target, and the comparison that decides
-``correct``.
+"""The comparison that decides ``correct``, shared by every architecture,
+and the plain reference of the dense target.
 
-The reference is a straightforward decoder forward (Qwen2 / Qwen3
+An architecture's reference module (this one for a configuration that
+names no ``model``; ``bench/archs/<name>_reference.py`` for one that
+does, see ``bench/spec.py``) provides one function::
+
+    tail_logits(w, tokens, start, *, arch, n_out, precision) -> [n_out, V]
+
+the float32 logits at positions ``start .. start+n_out-1`` of one
+sequence ``tokens`` (causal, positions 0..T-1) over the benchmark's
+weights ``w`` (its architecture module's layout), computed from the
+configuration file alone: ``precision="fp32"`` in float32 with every
+matrix product at ``Precision.HIGHEST``; ``precision="fp8"`` the control,
+with every weight matrix rounded to float8 e4m3 (one scale per output
+channel) and activations in bfloat16, the next precision below the
+configurations' bfloat16. It imports nothing of the program. The pieces
+below (:func:`rms`, :func:`rope`, :func:`attention`,
+:func:`decoder_layer`, :func:`embed`, :func:`head_logits`,
+:func:`precision_of`) are there for a reference module to reuse; an architecture brings its own
+mathematics and no comparison of its own.
+
+The dense reference is a straightforward decoder forward (Qwen2 / Qwen3
 family: RMSNorm, RoPE on rotated halves, grouped-query attention with
 optional q/k/v biases and per-head q/k RMSNorm, SwiGLU MLP, tied or
-untied head) written in ``jax.numpy`` from the configuration file alone.
-It imports nothing of the program. It computes in float32 with every
-matrix product at ``Precision.HIGHEST``, one layer at a time, over the
-benchmark's own weights (``bench/model.py``).
+untied head), one layer at a time, over ``bench/model.py``'s weights.
 
-The comparison: for a served request with prompt ``p`` and served tokens
-``s``, the reference runs once over ``p + s`` and, at every position that
-produced a served token, reads how far the served token's logit lies
-below the reference's best logit there. ``widest_gap`` is the largest
-such gap over the requests a run checks. Greedy serving that computes what
-the configuration states leaves only rounding in it.
-
-The control (``precision="fp8"``) is the same forward with every weight
-matrix rounded to float8 e4m3 (per output channel scale) and activations
-in bfloat16: the next precision below the configuration's bfloat16. Its
-gap is read for the token it puts first at each position.
+The comparison (:func:`gaps`): for a served request with prompt ``p`` and
+served tokens ``s``, the cell's ``tail_logits`` runs once over ``p + s``
+and, at every position that produced a served token, reads how far the
+served token's logit lies below the reference's best logit there.
+``widest_gap`` is the largest such gap over the requests a run checks.
+Greedy serving that computes what the configuration states leaves only
+rounding in it. The control's gap is read for the token it puts first at
+each position.
 """
 from __future__ import annotations
 
@@ -35,13 +48,13 @@ T_STEP = 1024           # sequence lengths are multiples of this
 OUT_STEP = 256          # served positions are read in multiples of this
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x [T, H, Dh]: rotate the two halves of each head."""
     dh = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
@@ -62,81 +75,108 @@ def _fp8(w):
     return (q.astype(jnp.float32) * s).astype(jnp.bfloat16)
 
 
-def _forward_hidden(w, tokens, arch, act, quant):
-    """Final-normed hidden states [T, d] of one sequence ``tokens`` [T]
-    (causal; positions 0..T-1), activations in ``act``, every weight
-    matrix passed through ``quant`` where it is used (one layer at a
-    time)."""
-    t = tokens.shape[0]
-    pos = jnp.arange(t)
-    hq, hkv, dh = arch.heads, arch.kv_heads, arch.head_dim
-    g = hq // hkv
-    prec = HI if act == jnp.float32 else None
+def precision_of(precision):
+    """(activation dtype, weight rounding, matrix-product precision) of a
+    reference precision."""
+    if precision == "fp32":
+        return jnp.float32, lambda m: m, HI
+    if precision == "fp8":
+        return jnp.bfloat16, _fp8, None
+    raise ValueError(f"unknown reference precision {precision!r}")
 
-    def mm(x, m):
-        return jnp.einsum("ti,io->to", x, quant(m).astype(act),
+
+def attention(q, k, v, *, prec, window=None):
+    """Causal grouped-query attention of one sequence in float32, in blocks
+    of ``Q_BLOCK`` query rows: q [T, Hq, Dh] and k, v [T, Hkv, Dh], after
+    RoPE; returns [T, Hq, Dh]. With ``window``, the query at position p
+    reads the keys at p - window + 1 .. p."""
+    t, hq, dh = q.shape
+    hkv = k.shape[1]
+    pos = jnp.arange(t)
+    qb = (q.astype(jnp.float32) * dh ** -0.5).reshape(
+        t // Q_BLOCK, Q_BLOCK, hkv, hq // hkv, dh)
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    def block(args):
+        qi, i = args
+        s = jnp.einsum("qhgd,khd->hgqk", qi, kf, precision=prec)
+        qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
+        seen = pos[None, :] <= qpos[:, None]
+        if window is not None:
+            seen &= pos[None, :] > qpos[:, None] - window
+        s = jnp.where(seen, s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("hgqk,khd->qhgd", p, vf, precision=prec)
+
+    return jax.lax.map(block, (qb, jnp.arange(t // Q_BLOCK))).reshape(
+        t, hq, dh)
+
+
+def decoder_layer(x, lw, pos, *, arch, act, quant, prec, window=None):
+    """One pre-norm decoder layer of the dense family over x [T, d]:
+    attention (q/k/v biases and q/k RMSNorm as ``arch`` states, RoPE, a
+    sliding ``window`` where given), then a SwiGLU MLP; the weights of
+    ``lw`` (one layer of ``bench/model.py``'s ``layer_stack``) pass
+    through ``quant`` where they are used."""
+    t = x.shape[0]
+    hq, hkv, dh = arch.heads, arch.kv_heads, arch.head_dim
+
+    def mm(y, m):
+        return jnp.einsum("ti,io->to", y, quant(m).astype(act),
                           precision=prec)
 
-    def layer(x, lw):
-        h = _rms(x, lw["attn_norm"], arch.eps)
-        q, k, v = mm(h, lw["wq"]), mm(h, lw["wk"]), mm(h, lw["wv"])
-        if arch.qkv_bias:
-            q = q + lw["bq"].astype(act)
-            k = k + lw["bk"].astype(act)
-            v = v + lw["bv"].astype(act)
-        q, k, v = (q.reshape(t, hq, dh), k.reshape(t, hkv, dh),
-                   v.reshape(t, hkv, dh))
-        if arch.qk_norm:
-            q = _rms(q, lw["q_norm"], arch.eps)
-            k = _rms(k, lw["k_norm"], arch.eps)
-        q, k = _rope(q, pos, arch.theta), _rope(k, pos, arch.theta)
-        qb = (q.astype(jnp.float32) * dh ** -0.5).reshape(
-            t // Q_BLOCK, Q_BLOCK, hkv, g, dh)
-        kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
-
-        def block(args):
-            qi, i = args
-            s = jnp.einsum("qhgd,khd->hgqk", qi, kf, precision=prec)
-            qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-            s = jnp.where(pos[None, :] <= qpos[:, None], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum("hgqk,khd->qhgd", p, vf, precision=prec)
-
-        o = jax.lax.map(block, (qb, jnp.arange(t // Q_BLOCK)))
-        o = o.reshape(t, hq * dh).astype(act)
-        x = x + mm(o, lw["wo"])
-        h = _rms(x, lw["mlp_norm"], arch.eps)
-        gate = mm(h, lw["w_gate"]).astype(jnp.float32)
-        up = mm(h, lw["w_up"]).astype(jnp.float32)
-        x = x + mm((jax.nn.silu(gate) * up).astype(act), lw["w_down"])
-        return x, None
-
-    x = quant(w["embed"][tokens].T).T.astype(act)
-    x, _ = jax.lax.scan(layer, x, w["layers"])
-    return _rms(x, w["final_norm"], arch.eps)
+    h = rms(x, lw["attn_norm"], arch.eps)
+    q, k, v = mm(h, lw["wq"]), mm(h, lw["wk"]), mm(h, lw["wv"])
+    if arch.qkv_bias:
+        q = q + lw["bq"].astype(act)
+        k = k + lw["bk"].astype(act)
+        v = v + lw["bv"].astype(act)
+    q, k, v = (q.reshape(t, hq, dh), k.reshape(t, hkv, dh),
+               v.reshape(t, hkv, dh))
+    if arch.qk_norm:
+        q = rms(q, lw["q_norm"], arch.eps)
+        k = rms(k, lw["k_norm"], arch.eps)
+    q, k = rope(q, pos, arch.theta), rope(k, pos, arch.theta)
+    o = attention(q, k, v, prec=prec, window=window)
+    x = x + mm(o.reshape(t, hq * dh).astype(act), lw["wo"])
+    h = rms(x, lw["mlp_norm"], arch.eps)
+    gate = mm(h, lw["w_gate"]).astype(jnp.float32)
+    up = mm(h, lw["w_up"]).astype(jnp.float32)
+    return x + mm((jax.nn.silu(gate) * up).astype(act), lw["w_down"])
 
 
-def _precision(precision):
-    """(activation dtype, weight rounding) of a reference precision."""
-    if precision == "fp32":
-        return jnp.float32, lambda m: m
-    if precision == "fp8":
-        return jnp.bfloat16, _fp8
-    raise ValueError(f"unknown reference precision {precision!r}")
+def embed(w, tokens, act, quant):
+    """The embedding rows of ``tokens``, in ``act``."""
+    return quant(w["embed"][tokens].T).T.astype(act)
+
+
+def head_logits(w, x, start, *, arch, n_out, act, quant, prec):
+    """Float32 logits [n_out, V] of the final-normed hidden states of
+    ``x`` [T, d] at positions ``start .. start+n_out-1``, through the tied
+    embedding or the untied ``head``."""
+    h = jax.lax.dynamic_slice_in_dim(rms(x, w["final_norm"], arch.eps),
+                                     start, n_out, 0)
+    head = w["embed"].T if arch.tied else w["head"]
+    return jnp.einsum("td,dv->tv", h, quant(head).astype(act),
+                      precision=prec).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("arch", "n_out", "precision"))
 def tail_logits(w, tokens, start, *, arch, n_out, precision="fp32"):
-    """Float32 logits [n_out, V] at positions ``start .. start+n_out-1`` of
-    ``tokens`` [T] (T a multiple of ``Q_BLOCK``; padding after the
-    request's last token does not reach these positions)."""
-    act, quant = _precision(precision)
-    h = _forward_hidden(w, tokens, arch, act, quant)
-    h = jax.lax.dynamic_slice_in_dim(h, start, n_out, 0)
-    head = w["embed"].T if arch.tied else w["head"]
-    prec = HI if act == jnp.float32 else None
-    return jnp.einsum("td,dv->tv", h, quant(head).astype(act),
-                      precision=prec).astype(jnp.float32)
+    """The dense target's float32 logits [n_out, V] at positions ``start
+    .. start+n_out-1`` of ``tokens`` [T] (T a multiple of ``Q_BLOCK``;
+    padding after the request's last token does not reach these
+    positions); every layer attends to the whole context."""
+    act, quant, prec = precision_of(precision)
+    pos = jnp.arange(tokens.shape[0])
+
+    def layer(x, lw):
+        return decoder_layer(x, lw, pos, arch=arch, act=act, quant=quant,
+                             prec=prec), None
+
+    x, _ = jax.lax.scan(layer, embed(w, tokens, act, quant), w["layers"])
+    return head_logits(w, x, start, arch=arch, n_out=n_out, act=act,
+                       quant=quant, prec=prec)
 
 
 @jax.jit
@@ -162,10 +202,11 @@ def out_len(n: int) -> int:
     return -(-n // OUT_STEP) * OUT_STEP
 
 
-def gaps(w, arch, prompt, served, *, t_len, n_out, control=False):
-    """Gaps of one request: (served-token gaps [n], control gaps [n] or
-    None). ``served`` are the tokens the program produced after
-    ``prompt``."""
+def gaps(tail_logits, w, arch, prompt, served, *, t_len, n_out,
+         control=False):
+    """Gaps of one request against the cell's ``tail_logits``: (served-token
+    gaps [n], control gaps [n] or None). ``served`` are the tokens the
+    program produced after ``prompt``."""
     p, n = len(prompt), len(served)
     assert n <= n_out and p + n <= t_len, (p, n, t_len, n_out)
     seq = np.zeros((t_len,), np.int32)

@@ -1,7 +1,9 @@
 """The comparison that decides ``correct``, at tiny widths on the CPU:
 the served tokens of the driver loop agree with the plain reference; the
-reference's control in a lower precision does not; and a run whose timed
-path is broken underneath comes out not correct."""
+reference's control in a lower precision does not; a run whose timed
+path is broken underneath comes out not correct; and an architecture
+added as files only is held to its own reference's mathematics."""
+import dataclasses
 import time
 
 import jax
@@ -122,3 +124,43 @@ def test_a_traced_run_reports_its_per_layer_metrics(tmp_path, capsys,
         "mfu", "idle_share.decode"}
     assert len(res["breakdown"]["device_ops"]) <= 10
     assert res["breakdown"]["idle_gaps"][0][0] == "bench.complete_cycle"
+
+
+def _window_blind(arch_ref):
+    """``arch_ref.tail_logits`` with the sliding windows wider than any
+    sequence: every layer attends to the whole context."""
+    def tail_logits(w, tokens, start, *, arch, n_out, precision="fp32"):
+        return arch_ref.tail_logits(
+            w, tokens, start, n_out=n_out, precision=precision,
+            arch=dataclasses.replace(arch, window=1 << 30))
+    return tail_logits
+
+
+def test_an_architecture_added_as_files_is_served_and_held_to_its_reference(
+        tmp_path, capsys, monkeypatch, quiet_cache):
+    """The sliding-window decoder of ``data/archs/tiny_window*.py``, added
+    to a checkout by new files and ``BENCHMARK.json`` entries only, is
+    served through the engine and agrees with its own reference; the same
+    run checked against that reference blind to the window is not
+    correct."""
+    root = tiny_checkout(tmp_path)
+    runs = []
+
+    class Kept(harness.Bench):
+        def __init__(self, *args):
+            super().__init__(*args)
+            runs.append(self)
+    monkeypatch.setattr(harness, "Bench", Kept)
+    res = harness.main(["--workload", "tiny-window.closed", "--seed", "4",
+                        "--seconds", "2", "--trace", "0"],
+                       time.perf_counter(), root, require_tpu=False)
+    assert res["correct"] is True, res["checks"]
+    assert res["metrics"]["tokens_per_s"]["value"] > 0
+    b, = runs
+    assert b.arch.pattern == ("local", "global") and b.arch.window == 8
+    b.tail_logits = _window_blind(spec.load_cell(
+        root, "tiny-window.closed").reference)
+    correct, checks = harness.verdict(b, b.check())
+    assert correct is False
+    assert checks["tokens_checked"] == res["checks"]["tokens_checked"]
+    assert checks["widest_gap"]["value"] > 10 * checks["widest_gap"]["limit"]

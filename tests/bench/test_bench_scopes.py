@@ -186,6 +186,41 @@ def test_a_scope_counts_a_nested_kernel_once():
 def test_the_verify_read_is_told_from_the_drafters_by_its_shape():
     assert ct.verify_read(HAND["ops"], 4, -10, 255) == (60, 2)
     assert ct.verify_read(HAND["ops"], 2, -10, 255) == (0, 0)
+    # a sliding-window layer reads its rolling buffer through the dense
+    # kernel; another kernel of the same shape is no read of the cache
+    ops = HAND["ops"] + [_op(ct.DENSE_KERNEL, 200, 7),
+                         _op("flash_attention_fwd", 210, 5)]
+    assert ct.verify_read(ops, 4, -10, 255) == (67, 3)
+
+
+def test_the_roofline_reader_reads_a_window_architecture(tiny):
+    """``cascade_read_roofline`` of the tiny (local, global) decoder of
+    ``data/archs/tiny_window.py``: per cycle one dense-kernel call (the
+    window layer) and one paged call; the least time counts the window
+    layer's ``min(ctx, window)`` tokens."""
+    from types import SimpleNamespace as NS
+    from bench.driver import Cycle
+    root, _ = tiny
+    arch_mod, _ = spec.arch_modules(
+        root, root / "bench" / "configs" / "tiny-window.json")
+    arch = arch_mod.Arch(layers=2, d=64, heads=4, kv_heads=2, head_dim=16,
+                         ff=128, vocab=512, eps=1e-6, theta=1e4, tied=False,
+                         qkv_bias=False, qk_norm=False, dtype="bfloat16",
+                         window=8, pattern=("local", "global"))
+    assert [arch.attended(n) for n in (5, 8, 100)] == [10, 16, 108]
+    ops = [_op(ct.DENSE_KERNEL, 10, 20), _op(ct.KERNEL, 40, 30)]
+    trace = {"ops": ops, "modules": [("jit_decode_cycle(1)", 0, 100)],
+             "spans": [("bench.complete_cycle", 0, 100)]}
+    peak = {"bf16_flops": 1e13, "hbm_bytes_per_s": 1e12}
+    run = NS(arch=arch, peak=peak, trace=trace,
+             summary=trace_reduce.summarize(trace),
+             cycles=[Cycle(0.0, 0.1, rows=4, lens=[5, 100], n_out=[1, 1],
+                           pool_use=0.5)])
+    tokens = (5 + 5) + (8 + 100)        # the window layer, the global one
+    least = max(4 * 4 * 16 * ct.TREE_NODES * tokens / 1e13,
+                2 * 2 * 16 * 2 * tokens / 1e12)
+    got = spec.reader(root, "cascade_read_roofline.decode")(run)
+    assert got == pytest.approx(100 * least / 50e-9)
 
 
 def test_idle_goes_to_the_innermost_engine_span():
